@@ -34,6 +34,8 @@ def main() -> None:
     ap.add_argument("--only", default=None)
     ap.add_argument("--out", default="reports/bench")
     args = ap.parse_args()
+    from repro.launch.drive import enable_compile_cache
+    enable_compile_cache()
 
     os.makedirs(args.out, exist_ok=True)
     all_rows = []

@@ -19,13 +19,13 @@ Two entry points share the kernel math:
     valid length are clamped to the last valid block in the index map
     (a repeated block index skips the DMA) and their compute is skipped.
 
-Layout note: q rows per program = rep (GQA group fan-out, 1–8).  On real
-TPUs rows < 8 under-fill sublanes; production layout would fold multiple
-KV heads per program — kept simple here and validated in interpret mode.
-The arena form reads (1, block_k, 1, D) blocks straight from the arena's
-native (slots, S, Hkv, D) layout, trading sublane fill for zero arena
-reshuffling (a transpose would copy the whole arena and defeat the
-in-place point).
+Layout note: the arena and paged forms read (1, block_k, Hkv, D)
+blocks straight from the pool's native (slots|pages, S, Hkv, D) layout
+— a transpose would copy the whole pool and defeat the in-place point.
+A block's two trailing dims must be multiples of the TPU tile or equal
+the array's own, so one block carries EVERY KV head (a size-1 head block
+does not compile for the chip) and one program serves all query heads of
+its row, selecting each head's rows inside the kernel.
 """
 from __future__ import annotations
 
@@ -36,8 +36,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels._compat import CompilerParams
 
 NEG_INF = -1e30
 LANES = 128
@@ -55,7 +53,7 @@ def _largest_divisor(n: int, cap: int) -> int:
 def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
             scale: float, block_k: int, n_kv_blocks: int):
     ki = pl.program_id(2)
-    kv_len = len_ref[0, 0]
+    kv_len = len_ref[pl.program_id(0)]
 
     @pl.when(ki == 0)
     def _init():
@@ -120,36 +118,46 @@ def decode_attn(q: jax.Array, k: jax.Array, v: jax.Array,
 
     kern = functools.partial(_kernel, scale=d ** -0.5, block_k=block_k,
                              n_kv_blocks=nk)
-    out = pl.pallas_call(
-        kern,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(b, hkv, nk),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda bb, g, ki: (bb, 0)),
-            pl.BlockSpec((1, 1, rep, d), lambda bb, g, ki: (bb, g, 0, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda bb, g, ki: (bb, g, ki, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda bb, g, ki: (bb, g, ki, 0)),
+            pl.BlockSpec((1, 1, rep, d), lambda bb, g, ki, *_: (bb, g, 0, 0)),
+            pl.BlockSpec((1, 1, block_k, d),
+                         lambda bb, g, ki, *_: (bb, g, ki, 0)),
+            pl.BlockSpec((1, 1, block_k, d),
+                         lambda bb, g, ki, *_: (bb, g, ki, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, rep, d), lambda bb, g, ki: (bb, g, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, hkv, rep, d), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, rep, d),
+                               lambda bb, g, ki, *_: (bb, g, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((rep, LANES), jnp.float32),
             pltpu.VMEM((rep, LANES), jnp.float32),
             pltpu.VMEM((rep, d), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+    )
+    out = pl.pallas_call(
+        kern,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, hkv, rep, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(lengths.reshape(b, 1).astype(jnp.int32), qg, kt, vt)
+    )(lengths.astype(jnp.int32), qg, kt, vt)
     return out.reshape(b, hq, d)
 
 
-def _arena_kernel(slot_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
+def _arena_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
                   m_ref, l_ref, acc_ref, *, scale: float,
                   window: Optional[int], depth: int, block_k: int,
                   n_kv_blocks: int, n_phys_blocks: int):
-    del slot_ref                     # consumed by the BlockSpec index maps
+    """One (row, kv block) program over ALL heads: the kv block carries
+    every KV head, so each fetched block serves every query head of the
+    row (static loop over KV heads, each a (rep, D) × (D, block_k)
+    product)."""
+    del tbl_ref                      # consumed by the BlockSpec index maps
     b = pl.program_id(0)
-    ki = pl.program_id(2)
+    ki = pl.program_id(1)
     kv_len = len_ref[b]
     if window is None:
         n_valid = kv_len
@@ -174,14 +182,9 @@ def _arena_kernel(slot_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(k_start < n_valid)
     def _compute():
-        q = q_ref[0, 0]                                        # (rep, D)
-        k = k_ref[0, :, 0, :]                                  # (bk, D)
-        v = v_ref[0, :, 0, :]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale        # (rep, bk)
+        rep = q_ref.shape[2]
         slot = k_start + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
+            jnp.int32, (rep, block_k), 1)
         mask = slot < n_valid
         if window is not None:
             # rolling slot s holds the newest position < kv_len congruent
@@ -190,25 +193,75 @@ def _arena_kernel(slot_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
             wraps = jnp.maximum(kv_len - 1 - slot, 0) // depth
             kpos = slot + wraps * depth
             mask = jnp.logical_and(mask, kpos > kv_len - 1 - window)
-        s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_ref[:, :1]
-        l_prev = l_ref[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        acc_ref[...] = acc_ref[...] * alpha + pv
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+        for g in range(k_ref.shape[2]):
+            q = q_ref[0, g]                                    # (rep, D)
+            k = k_ref[0, :, g, :]                              # (bk, D)
+            v = v_ref[0, :, g, :]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale    # (rep, bk)
+            s = jnp.where(mask, s, NEG_INF)
+            m_prev = m_ref[g][:, :1]
+            l_prev = l_ref[g][:, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+            alpha = jnp.exp(m_prev - m_new)
+            l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+            pv = jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            acc_ref[g] = acc_ref[g] * alpha + pv
+            m_ref[g] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+            l_ref[g] = jnp.broadcast_to(l_new, l_ref.shape[1:])
 
     @pl.when(ki == n_kv_blocks - 1)
     def _finish():
-        l = l_ref[:, :1]
+        l = l_ref[:, :, :1]
         l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+
+
+def _arena_call(kv_map, table, lengths, q, k, v, *, window: Optional[int],
+                depth: int, block_k: int, n_kv_blocks: int,
+                n_phys_blocks: int, interpret: bool) -> jax.Array:
+    """Shared pallas_call of the arena and paged forms.  Grid = (row, kv
+    block); each program holds every query head of its row and reads
+    (1, block_k, Hkv, D) kv blocks, whose two trailing dims are the
+    pool's own — the TPU tiling rule for a block's last two dims — so
+    one DMA per block serves all heads."""
+    b, hq, d = q.shape
+    hkv = k.shape[2]
+    rep = hq // hkv
+    qg = q.reshape(b, hkv, rep, d)
+    kern = functools.partial(_arena_kernel, scale=d ** -0.5, window=window,
+                             depth=depth, block_k=block_k,
+                             n_kv_blocks=n_kv_blocks,
+                             n_phys_blocks=n_phys_blocks)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b, n_kv_blocks),
+        in_specs=[
+            pl.BlockSpec((1, hkv, rep, d), lambda bb, ki, *_: (bb, 0, 0, 0)),
+            pl.BlockSpec((1, block_k, hkv, d), kv_map),
+            pl.BlockSpec((1, block_k, hkv, d), kv_map),
+        ],
+        out_specs=pl.BlockSpec((1, hkv, rep, d),
+                               lambda bb, ki, *_: (bb, 0, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((hkv, rep, LANES), jnp.float32),
+            pltpu.VMEM((hkv, rep, LANES), jnp.float32),
+            pltpu.VMEM((hkv, rep, d), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        kern,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, hkv, rep, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )(table.astype(jnp.int32), lengths.astype(jnp.int32), qg, k, v)
+    return out.reshape(b, hq, d)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
@@ -244,58 +297,29 @@ def decode_attn_paged(q: jax.Array, k: jax.Array, v: jax.Array,
     modularly, exactly :func:`decode_attn_arena`'s windowed form with
     the page-id lookup replacing the slot-id lookup.
     """
-    b, hq, d = q.shape
-    ps, hkv = k.shape[1], k.shape[2]
+    ps = k.shape[1]
     p_max = page_table.shape[1]
-    rep = hq // hkv
-    block_k = ps                   # the page IS the kv block
-    nk = p_max
-    nk_iter = nk if window is None else min(nk, (window - 1) // block_k + 2)
+    nk = p_max                     # the page IS the kv block
+    nk_iter = nk if window is None else min(nk, (window - 1) // ps + 2)
     depth = ps * p_max
-    qg = q.reshape(b, hkv, rep, d)
 
-    def kv_map(bb, g, ki, pt_ref, len_ref):
+    def kv_map(bb, ki, pt_ref, len_ref):
         if window is None:
-            last = jnp.maximum(len_ref[bb] - 1, 0) // block_k
-            return (pt_ref[bb, jnp.minimum(ki, last)], 0, g, 0)
+            last = jnp.maximum(len_ref[bb] - 1, 0) // ps
+            return (pt_ref[bb, jnp.minimum(ki, last)], 0, 0, 0)
         kvl = len_ref[bb]
         n_valid = jnp.minimum(kvl, depth)
         w_eff = jnp.minimum(window, kvl)
         s0 = (kvl - w_eff) % depth      # oldest in-window ring slot
-        phys = (s0 // block_k + ki) % nk
+        phys = (s0 // ps + ki) % nk
         # pre-wraparound (kvl < depth) the walk cannot wrap, so clamping
         # to the last valid page only retargets pages the kernel skips
-        last = jnp.maximum(n_valid - 1, 0) // block_k
-        return (pt_ref[bb, jnp.minimum(phys, last)], 0, g, 0)
+        last = jnp.maximum(n_valid - 1, 0) // ps
+        return (pt_ref[bb, jnp.minimum(phys, last)], 0, 0, 0)
 
-    kern = functools.partial(_arena_kernel, scale=d ** -0.5, window=window,
-                             depth=depth, block_k=block_k,
-                             n_kv_blocks=nk_iter, n_phys_blocks=nk)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, hkv, nk_iter),
-        in_specs=[
-            pl.BlockSpec((1, 1, rep, d), lambda bb, g, ki, *_: (bb, g, 0, 0)),
-            pl.BlockSpec((1, block_k, 1, d), kv_map),
-            pl.BlockSpec((1, block_k, 1, d), kv_map),
-        ],
-        out_specs=pl.BlockSpec((1, 1, rep, d),
-                               lambda bb, g, ki, *_: (bb, g, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((rep, LANES), jnp.float32),
-            pltpu.VMEM((rep, LANES), jnp.float32),
-            pltpu.VMEM((rep, d), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kern,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, rep, d), q.dtype),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(page_table.astype(jnp.int32), lengths.astype(jnp.int32), qg, k, v)
-    return out.reshape(b, hq, d)
+    return _arena_call(kv_map, page_table, lengths, q, k, v, window=window,
+                       depth=depth, block_k=ps, n_kv_blocks=nk_iter,
+                       n_phys_blocks=nk, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "block_k",
@@ -323,9 +347,7 @@ def decode_attn_arena(q: jax.Array, k: jax.Array, v: jax.Array,
     keys inside the query's window survive the mask — O(min(cached,
     window)) HBM rows per generated token instead of O(cached).
     """
-    b, hq, d = q.shape
-    s, hkv = k.shape[1], k.shape[2]
-    rep = hq // hkv
+    s = k.shape[1]
     block_k = _largest_divisor(s, block_k)
     nk = s // block_k
     # windowed form: the in-window slots are a cyclic contiguous range
@@ -333,14 +355,13 @@ def decode_attn_arena(q: jax.Array, k: jax.Array, v: jax.Array,
     # range can touch — the walk starts at the oldest in-window slot's
     # block and wraps modularly (see kv_map/_arena_kernel)
     nk_iter = nk if window is None else min(nk, (window - 1) // block_k + 2)
-    qg = q.reshape(b, hkv, rep, d)
 
-    def kv_map(bb, g, ki, slot_ref, len_ref):
+    def kv_map(bb, ki, slot_ref, len_ref):
         # clamp past-the-length blocks to the last valid one: a repeated
         # block index is not re-fetched, so invalid blocks cost no DMA.
         if window is None:
             last = jnp.maximum(len_ref[bb] - 1, 0) // block_k
-            return (slot_ref[bb], jnp.minimum(ki, last), g, 0)
+            return (slot_ref[bb], jnp.minimum(ki, last), 0, 0)
         kvl = len_ref[bb]
         n_valid = jnp.minimum(kvl, s)
         w_eff = jnp.minimum(window, kvl)
@@ -349,33 +370,8 @@ def decode_attn_arena(q: jax.Array, k: jax.Array, v: jax.Array,
         # pre-wraparound (kvl < s) the walk cannot wrap, so clamping to
         # the last valid block only retargets blocks the kernel skips
         last = jnp.maximum(n_valid - 1, 0) // block_k
-        return (slot_ref[bb], jnp.minimum(phys, last), g, 0)
+        return (slot_ref[bb], jnp.minimum(phys, last), 0, 0)
 
-    kern = functools.partial(_arena_kernel, scale=d ** -0.5, window=window,
-                             depth=s, block_k=block_k, n_kv_blocks=nk_iter,
-                             n_phys_blocks=nk)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, hkv, nk_iter),
-        in_specs=[
-            pl.BlockSpec((1, 1, rep, d), lambda bb, g, ki, *_: (bb, g, 0, 0)),
-            pl.BlockSpec((1, block_k, 1, d), kv_map),
-            pl.BlockSpec((1, block_k, 1, d), kv_map),
-        ],
-        out_specs=pl.BlockSpec((1, 1, rep, d),
-                               lambda bb, g, ki, *_: (bb, g, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((rep, LANES), jnp.float32),
-            pltpu.VMEM((rep, LANES), jnp.float32),
-            pltpu.VMEM((rep, d), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kern,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, rep, d), q.dtype),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(slot_map.astype(jnp.int32), lengths.astype(jnp.int32), qg, k, v)
-    return out.reshape(b, hq, d)
+    return _arena_call(kv_map, slot_map, lengths, q, k, v, window=window,
+                       depth=s, block_k=block_k, n_kv_blocks=nk_iter,
+                       n_phys_blocks=nk, interpret=interpret)

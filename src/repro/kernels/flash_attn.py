@@ -27,8 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 NEG_INF = -1e30
 LANES = 128
 
@@ -39,8 +37,8 @@ def _kernel(off_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
             n_kv_blocks: int):
     ki = pl.program_id(3)
     qi = pl.program_id(2)
-    offset = off_ref[0, 0]
-    kv_len = len_ref[0, 0]
+    offset = off_ref[pl.program_id(0)]
+    kv_len = len_ref[pl.program_id(0)]
 
     @pl.when(ki == 0)
     def _init():
@@ -137,29 +135,33 @@ def flash_attn(q: jax.Array, k: jax.Array, v: jax.Array,
     kern = functools.partial(
         _kernel, scale=d ** -0.5, causal=causal, window=window,
         block_q=block_q, block_k=block_k, n_kv_blocks=nk)
-    out = pl.pallas_call(
-        kern,
+    # offsets and lengths ride in SMEM as scalar prefetch: a (1, 1)
+    # block over a (B, 1) array does not meet the TPU tiling rule
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1), lambda bb, h, qi, ki: (bb, 0)),
-            pl.BlockSpec((1, 1), lambda bb, h, qi, ki: (bb, 0)),
-            pl.BlockSpec((1, 1, block_q, d), lambda bb, h, qi, ki: (bb, h, qi, 0)),
+            pl.BlockSpec((1, 1, block_q, d),
+                         lambda bb, h, qi, ki, *_: (bb, h, qi, 0)),
             pl.BlockSpec((1, 1, block_k, d),
-                         lambda bb, h, qi, ki: (bb, h // rep, ki, 0)),
+                         lambda bb, h, qi, ki, *_: (bb, h // rep, ki, 0)),
             pl.BlockSpec((1, 1, block_k, d),
-                         lambda bb, h, qi, ki: (bb, h // rep, ki, 0)),
+                         lambda bb, h, qi, ki, *_: (bb, h // rep, ki, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, d),
-                               lambda bb, h, qi, ki: (bb, h, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, hq, lq_pad, d), q.dtype),
+                               lambda bb, h, qi, ki, *_: (bb, h, qi, 0)),
         scratch_shapes=[
             pltpu.VMEM((block_q, LANES), jnp.float32),
             pltpu.VMEM((block_q, LANES), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+    )
+    out = pl.pallas_call(
+        kern,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, hq, lq_pad, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(q_offsets.reshape(b, 1).astype(jnp.int32),
-      kv_lengths.reshape(b, 1).astype(jnp.int32), qt, kt, vt)
+    )(q_offsets.astype(jnp.int32), kv_lengths.astype(jnp.int32), qt, kt, vt)
     return jnp.moveaxis(out[:, :, :lq], 1, 2)

@@ -38,7 +38,7 @@ def set_backend(mode: Optional[str]) -> None:
     _FORCE = mode
 
 
-def _on_tpu() -> bool:
+def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
@@ -47,7 +47,7 @@ def _use_pallas() -> bool:
         return True
     if _FORCE == "ref":
         return False
-    return _on_tpu()
+    return on_tpu()
 
 
 def mha(q, k, v, q_offsets=None, kv_lengths=None, *, causal=True,
@@ -56,7 +56,7 @@ def mha(q, k, v, q_offsets=None, kv_lengths=None, *, causal=True,
     if _use_pallas():
         return _flash_pallas(q, k, v, q_offsets, kv_lengths, causal=causal,
                              window=window, block_q=block_q, block_k=block_k,
-                             interpret=not _on_tpu())
+                             interpret=not on_tpu())
     return ref_mod.ref_flash_attn(q, k, v, q_offsets=q_offsets,
                                   kv_lengths=kv_lengths, window=window,
                                   causal=causal)
@@ -69,7 +69,7 @@ def ragged_mha(q, k, v, cu_seqlens, q_offsets=None, kv_lengths=None, *,
     if _use_pallas():
         return _ragged_pallas(q, k, v, cu_seqlens, q_offsets, kv_lengths,
                               causal=causal, block_q=block_q,
-                              block_k=block_k, interpret=not _on_tpu())
+                              block_k=block_k, interpret=not on_tpu())
     return ref_mod.ref_ragged_prefill(q, k, v, cu_seqlens,
                                       q_offsets=q_offsets,
                                       kv_lengths=kv_lengths, causal=causal)
@@ -88,7 +88,7 @@ def ragged_mha_arena(q, k, v, slot_map, cu_seqlens, q_offsets=None,
                                     q_offsets, kv_lengths, causal=causal,
                                     window=window, block_q=block_q,
                                     block_k=block_k,
-                                    interpret=not _on_tpu())
+                                    interpret=not on_tpu())
     return ref_mod.ref_ragged_prefill_arena(q, k, v, slot_map, cu_seqlens,
                                             q_offsets=q_offsets,
                                             kv_lengths=kv_lengths,
@@ -108,7 +108,7 @@ def ragged_mha_paged(q, k, v, page_table, cu_seqlens, q_offsets=None,
         return _ragged_paged_pallas(q, k, v, page_table, cu_seqlens,
                                     q_offsets, kv_lengths, causal=causal,
                                     window=window, block_q=block_q,
-                                    interpret=not _on_tpu())
+                                    interpret=not on_tpu())
     return ref_mod.ref_ragged_prefill_paged(q, k, v, page_table, cu_seqlens,
                                             q_offsets=q_offsets,
                                             kv_lengths=kv_lengths,
@@ -119,7 +119,7 @@ def decode(q, k, v, lengths, *, block_k=512):
     """Single-token flash decode.  q: (B, Hq, D)."""
     if _use_pallas():
         return _decode_pallas(q, k, v, lengths, block_k=block_k,
-                              interpret=not _on_tpu())
+                              interpret=not on_tpu())
     return ref_mod.ref_decode_attn(q, k, v, lengths)
 
 
@@ -131,7 +131,7 @@ def decode_arena(q, k, v, slot_map, lengths, *, window=None, block_k=512):
     if _use_pallas():
         return _decode_arena_pallas(q, k, v, slot_map, lengths,
                                     window=window, block_k=block_k,
-                                    interpret=not _on_tpu())
+                                    interpret=not on_tpu())
     return ref_mod.ref_decode_attn_arena(q, k, v, slot_map, lengths,
                                          window=window)
 
@@ -143,7 +143,7 @@ def decode_paged(q, k, v, page_table, lengths, *, window=None):
     granularity) form.  See kernels.decode_attn.decode_attn_paged."""
     if _use_pallas():
         return _decode_paged_pallas(q, k, v, page_table, lengths,
-                                    window=window, interpret=not _on_tpu())
+                                    window=window, interpret=not on_tpu())
     return ref_mod.ref_decode_attn_paged(q, k, v, page_table, lengths,
                                          window=window)
 
@@ -157,7 +157,7 @@ def fused_sample(logits, temp, top_k, top_p, bias_ids, bias_vals, u, draft):
     if _use_pallas():
         return _fused_sample_pallas(logits, temp, top_k, top_p, bias_ids,
                                     bias_vals, u, draft,
-                                    interpret=not _on_tpu())
+                                    interpret=not on_tpu())
     return ref_mod.ref_fused_sample(logits, temp, top_k, top_p, bias_ids,
                                     bias_vals, u, draft)
 
@@ -166,5 +166,5 @@ def ssd(x, dt, a, bmat, cmat, init_state, *, chunk=128):
     """Chunked SSD scan.  See kernels.ssd_scan."""
     if _use_pallas():
         return _ssd_pallas(x, dt, a, bmat, cmat, init_state, chunk=chunk,
-                           interpret=not _on_tpu())
+                           interpret=not on_tpu())
     return ref_mod.ref_ssd_scan(x, dt, a, bmat, cmat, init_state=init_state)

@@ -40,8 +40,10 @@ Two entry points share the kernel math:
     only the valid cache prefixes of its live sessions — no whole-slot
     gather before the step and no scatter after it, killing the
     O(b_max · S_max) HBM round-trip of the gathered path.  Blocks read
-    (1, block_k, 1, D) straight from the arena's native layout — a
-    transpose would copy the arena and defeat the in-place point.
+    (1, block_k, Hkv, D) straight from the arena's native layout — a
+    transpose would copy the arena and defeat the in-place point — and
+    carry every KV head, since a block's two trailing dims must match
+    the TPU tile or the array's own; one program serves all query heads.
 
 Decode segments (continuous batching) need no special path: a length-1
 segment with ``q_offsets[i] = H`` and ``kv_lengths[i] = H + 1`` attends
@@ -50,8 +52,9 @@ scan at ``offset + 1`` blocks for that row, and kv blocks past the
 valid cache length are skipped before any VMEM traffic, so a decode
 row costs O(H) kv reads, not O(S_max).
 
-GQA reads the kv head as h // rep in the index maps, same as the dense
-kernel; accumulation is fp32 via ``preferred_element_type``.
+The gathered form reads the kv head as h // rep in the index maps; the
+arena and paged forms loop over heads inside the program.  Accumulation
+is fp32 via ``preferred_element_type``.
 """
 from __future__ import annotations
 
@@ -63,7 +66,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
 from repro.kernels.decode_attn import _largest_divisor
 
 NEG_INF = -1e30
@@ -206,7 +208,7 @@ def ragged_prefill_attn(q: jax.Array, k: jax.Array, v: jax.Array,
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((hq, t_pad, d), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel",
                                  "arbitrary", "arbitrary")),
         interpret=interpret,
@@ -215,14 +217,19 @@ def ragged_prefill_attn(q: jax.Array, k: jax.Array, v: jax.Array,
     return jnp.moveaxis(out[:, :t], 0, 1)
 
 
-def _arena_kernel(slot_ref, cu_ref, off_ref, len_ref, q_ref, k_ref, v_ref,
+def _arena_kernel(tbl_ref, cu_ref, off_ref, len_ref, q_ref, k_ref, v_ref,
                   o_ref, m_ref, l_ref, acc_ref, *, scale: float, causal: bool,
-                  window: Optional[int], depth: int,
+                  window: Optional[int], depth: int, rep: int,
                   block_q: int, block_k: int, n_seqs: int, n_kv_blocks: int):
-    del slot_ref                     # consumed by the BlockSpec index maps
-    qi = pl.program_id(1)
-    b = pl.program_id(2)
-    ki = pl.program_id(3)
+    """One (q block, segment, kv block) program over ALL heads: the kv
+    block carries every KV head, so each fetched block serves its whole
+    GQA group of query heads (static loop over KV heads, ``fori_loop``
+    over the group's query heads)."""
+    del tbl_ref                      # consumed by the BlockSpec index maps
+    qi = pl.program_id(0)
+    b = pl.program_id(1)
+    ki = pl.program_id(2)
+    hkv = k_ref.shape[2]
 
     @pl.when(jnp.logical_and(b == 0, ki == 0))
     def _init():
@@ -256,12 +263,6 @@ def _arena_kernel(slot_ref, cu_ref, off_ref, len_ref, q_ref, k_ref, v_ref,
 
     @pl.when(run)
     def _compute():
-        q = q_ref[0]                                           # (bq, D)
-        k = k_ref[0, :, 0, :]                                  # (bk, D)
-        v = v_ref[0, :, 0, :]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale        # (bq, bk)
         rows = q_start + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 0)                  # flat row ids
         slot = k_start + jax.lax.broadcasted_iota(
@@ -280,27 +281,91 @@ def _arena_kernel(slot_ref, cu_ref, off_ref, len_ref, q_ref, k_ref, v_ref,
             mask = jnp.logical_and(mask, kpos <= qpos)
         if window is not None:
             mask = jnp.logical_and(mask, kpos > qpos - window)
-        s = jnp.where(mask, s, NEG_INF)
 
-        m_prev = m_ref[:, :1]                                  # (bq, 1)
-        l_prev = l_ref[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        p = jnp.where(mask, p, 0.0)
-        alpha = jnp.exp(m_prev - m_new)                        # (bq, 1)
-        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)                # (bq, D)
-        acc_ref[...] = acc_ref[...] * alpha + pv
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+        for g in range(hkv):
+            k = k_ref[0, :, g, :]                              # (bk, D)
+            v = v_ref[0, :, g, :]
+
+            def head(r, carry, k=k, v=v, g=g):
+                h = g * rep + r
+                s = jax.lax.dot_general(
+                    q_ref[h], k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale  # (bq, bk)
+                s = jnp.where(mask, s, NEG_INF)
+                m_prev = m_ref[h][:, :1]                       # (bq, 1)
+                l_prev = l_ref[h][:, :1]
+                m_new = jnp.maximum(m_prev,
+                                    jnp.max(s, axis=-1, keepdims=True))
+                p = jnp.exp(s - m_new)
+                p = jnp.where(mask, p, 0.0)
+                alpha = jnp.exp(m_prev - m_new)                # (bq, 1)
+                l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+                pv = jax.lax.dot_general(
+                    p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)        # (bq, D)
+                acc_ref[h] = acc_ref[h] * alpha + pv
+                m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+                l_ref[h] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+                return carry
+
+            jax.lax.fori_loop(0, rep, head, 0)
 
     @pl.when(jnp.logical_and(b == n_seqs - 1, ki == n_kv_blocks - 1))
     def _finish():
-        l = l_ref[:, :1]
+        l = l_ref[:, :, :1]
         l = jnp.where(l == 0.0, 1.0, l)     # rows owned by no segment
-        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
+
+
+def _arena_call(kv_map, table, cu_seqlens, q_offsets, kv_lengths, q, k, v,
+                *, causal: bool, window: Optional[int], depth: int,
+                block_q: int, block_k: int, n_kv_blocks: int,
+                interpret: bool) -> jax.Array:
+    """Shared pallas_call of the arena and paged forms.  Grid = (q
+    block, segment, kv block); each program holds every query head of
+    its q block and reads (1, block_k, Hkv, D) kv blocks, whose two
+    trailing dims are the pool's own — the TPU tiling rule for a block's
+    last two dims — so one DMA per block serves all heads."""
+    t, hq, d = q.shape
+    hkv = k.shape[2]
+    b = table.shape[0]
+    block_q = min(block_q, max(t, 1))
+    t_pad = -(-t // block_q) * block_q
+    qt = jnp.moveaxis(q, 1, 0)                                 # (Hq, T, D)
+    if t_pad != t:
+        qt = jnp.pad(qt, ((0, 0), (0, t_pad - t), (0, 0)))
+    nq = t_pad // block_q
+
+    kern = functools.partial(
+        _arena_kernel, scale=d ** -0.5, causal=causal, window=window,
+        depth=depth, rep=hq // hkv, block_q=block_q, block_k=block_k,
+        n_seqs=b, n_kv_blocks=n_kv_blocks)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(nq, b, n_kv_blocks),
+        in_specs=[
+            pl.BlockSpec((hq, block_q, d), lambda qi, bb, ki, *_: (0, qi, 0)),
+            pl.BlockSpec((1, block_k, hkv, d), kv_map),
+            pl.BlockSpec((1, block_k, hkv, d), kv_map),
+        ],
+        out_specs=pl.BlockSpec((hq, block_q, d),
+                               lambda qi, bb, ki, *_: (0, qi, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((hq, block_q, LANES), jnp.float32),
+            pltpu.VMEM((hq, block_q, LANES), jnp.float32),
+            pltpu.VMEM((hq, block_q, d), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        kern,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((hq, t_pad, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        interpret=interpret,
+    )(table.astype(jnp.int32), cu_seqlens.astype(jnp.int32),
+      q_offsets.astype(jnp.int32), kv_lengths.astype(jnp.int32), qt, k, v)
+    return jnp.moveaxis(out[:, :t], 0, 1)
 
 
 @functools.partial(
@@ -351,64 +416,28 @@ def ragged_prefill_paged(q: jax.Array, k: jax.Array, v: jax.Array,
     :func:`ragged_prefill_arena`'s windowed form with the page-id
     lookup replacing the slot-id lookup.
     """
-    t, hq, d = q.shape
-    ps, hkv = k.shape[1], k.shape[2]
+    ps = k.shape[1]
     b, p_max = page_table.shape
-    rep = hq // hkv
     if q_offsets is None:
         q_offsets = jnp.zeros((b,), jnp.int32)
     if kv_lengths is None:
         kv_lengths = jnp.full((b,), ps * p_max, jnp.int32)
 
-    block_q = min(block_q, max(t, 1))
-    block_k = ps                   # the page IS the kv block
-    t_pad = -(-t // block_q) * block_q
-    qt = jnp.moveaxis(q, 1, 0)                                 # (Hq, T, D)
-    if t_pad != t:
-        qt = jnp.pad(qt, ((0, 0), (0, t_pad - t), (0, 0)))
-    nq, nk = t_pad // block_q, p_max
-
-    def kv_map(h, qi, bb, ki, pt_ref, cu_ref, off_ref, len_ref):
+    def kv_map(qi, bb, ki, pt_ref, cu_ref, off_ref, len_ref):
         # clamp past-the-length logical pages to the last valid one: a
         # repeated physical page is not re-fetched, so invalid pages
         # cost no DMA.  Ring tables have every page valid once
         # kv_len ≥ ps·P_max.
         n_valid = jnp.minimum(len_ref[bb], ps * p_max) \
             if window is not None else len_ref[bb]
-        last = jnp.maximum(n_valid - 1, 0) // block_k
-        return (pt_ref[bb, jnp.minimum(ki, last)], 0, h // rep, 0)
+        last = jnp.maximum(n_valid - 1, 0) // ps
+        return (pt_ref[bb, jnp.minimum(ki, last)], 0, 0, 0)
 
-    kern = functools.partial(
-        _arena_kernel, scale=d ** -0.5, causal=causal, window=window,
-        depth=ps * p_max, block_q=block_q, block_k=block_k, n_seqs=b,
-        n_kv_blocks=nk)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(hq, nq, b, nk),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda h, qi, bb, ki, *_: (h, qi, 0)),
-            pl.BlockSpec((1, block_k, 1, d), kv_map),
-            pl.BlockSpec((1, block_k, 1, d), kv_map),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d),
-                               lambda h, qi, bb, ki, *_: (h, qi, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((block_q, LANES), jnp.float32),
-            pltpu.VMEM((block_q, LANES), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kern,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((hq, t_pad, d), q.dtype),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel",
-                                 "arbitrary", "arbitrary")),
-        interpret=interpret,
-    )(page_table.astype(jnp.int32), cu_seqlens.astype(jnp.int32),
-      q_offsets.astype(jnp.int32), kv_lengths.astype(jnp.int32), qt, k, v)
-    return jnp.moveaxis(out[:, :t], 0, 1)
+    # the page IS the kv block
+    return _arena_call(kv_map, page_table, cu_seqlens, q_offsets,
+                       kv_lengths, q, k, v, causal=causal, window=window,
+                       depth=ps * p_max, block_q=block_q, block_k=ps,
+                       n_kv_blocks=p_max, interpret=interpret)
 
 
 @functools.partial(
@@ -451,60 +480,25 @@ def ragged_prefill_arena(q: jax.Array, k: jax.Array, v: jax.Array,
     own blocks; here a segment's queries span up to the whole valid
     range, so every valid block stays on the grid.)
     """
-    t, hq, d = q.shape
-    s, hkv = k.shape[1], k.shape[2]
+    s = k.shape[1]
     b = slot_map.shape[0]
-    rep = hq // hkv
     if q_offsets is None:
         q_offsets = jnp.zeros((b,), jnp.int32)
     if kv_lengths is None:
         kv_lengths = jnp.full((b,), s, jnp.int32)
-
-    block_q = min(block_q, max(t, 1))
     # the arena's S axis is never padded (padding would copy the arena)
     block_k = _largest_divisor(s, block_k)
-    t_pad = -(-t // block_q) * block_q
-    qt = jnp.moveaxis(q, 1, 0)                                 # (Hq, T, D)
-    if t_pad != t:
-        qt = jnp.pad(qt, ((0, 0), (0, t_pad - t), (0, 0)))
-    nq, nk = t_pad // block_q, s // block_k
 
-    def kv_map(h, qi, bb, ki, slot_ref, cu_ref, off_ref, len_ref):
+    def kv_map(qi, bb, ki, slot_ref, cu_ref, off_ref, len_ref):
         # clamp past-the-length blocks to the last valid one: a repeated
         # block index is not re-fetched, so invalid blocks cost no DMA.
         # Rolling arenas have every slot row valid once kv_len ≥ depth.
         n_valid = jnp.minimum(len_ref[bb], s) if window is not None \
             else len_ref[bb]
         last = jnp.maximum(n_valid - 1, 0) // block_k
-        return (slot_ref[bb], jnp.minimum(ki, last), h // rep, 0)
+        return (slot_ref[bb], jnp.minimum(ki, last), 0, 0)
 
-    kern = functools.partial(
-        _arena_kernel, scale=d ** -0.5, causal=causal, window=window,
-        depth=s, block_q=block_q, block_k=block_k, n_seqs=b, n_kv_blocks=nk)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(hq, nq, b, nk),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda h, qi, bb, ki, *_: (h, qi, 0)),
-            pl.BlockSpec((1, block_k, 1, d), kv_map),
-            pl.BlockSpec((1, block_k, 1, d), kv_map),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d),
-                               lambda h, qi, bb, ki, *_: (h, qi, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((block_q, LANES), jnp.float32),
-            pltpu.VMEM((block_q, LANES), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kern,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((hq, t_pad, d), q.dtype),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel",
-                                 "arbitrary", "arbitrary")),
-        interpret=interpret,
-    )(slot_map.astype(jnp.int32), cu_seqlens.astype(jnp.int32),
-      q_offsets.astype(jnp.int32), kv_lengths.astype(jnp.int32), qt, k, v)
-    return jnp.moveaxis(out[:, :t], 0, 1)
+    return _arena_call(kv_map, slot_map, cu_seqlens, q_offsets, kv_lengths,
+                       q, k, v, causal=causal, window=window, depth=s,
+                       block_q=block_q, block_k=block_k,
+                       n_kv_blocks=s // block_k, interpret=interpret)

@@ -36,8 +36,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 NEG = np.float32(-1e30)
 LANES = 128       # output lane width: scalars broadcast across one tile
 MAX_BIAS = 8      # logit-bias entries per row (engine falls back past it)
@@ -189,27 +187,30 @@ def fused_sample(logits, temp, top_k, top_p, bias_ids, bias_vals, u,
     Returns (token (R,) int32, p_draft (R,) float32, alt (R,) int32);
     only these (R,)-sized results ever cross to host."""
     r, v = logits.shape
-    outs = [jax.ShapeDtypeStruct((r, LANES), jnp.int32),
-            jax.ShapeDtypeStruct((r, LANES), jnp.float32),
-            jax.ShapeDtypeStruct((r, LANES), jnp.int32)]
+    # one (1, V) row per program: blocks squeeze a leading row axis so
+    # their two trailing dims equal the array's own (TPU tiling rule)
+    outs = [jax.ShapeDtypeStruct((r, 1, LANES), jnp.int32),
+            jax.ShapeDtypeStruct((r, 1, LANES), jnp.float32),
+            jax.ShapeDtypeStruct((r, 1, LANES), jnp.int32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=7,
         grid=(r,),
-        in_specs=[pl.BlockSpec((1, v), lambda i, *_: (i, 0))],
-        out_specs=[pl.BlockSpec((1, LANES), lambda i, *_: (i, 0))] * 3,
+        in_specs=[pl.BlockSpec((None, 1, v), lambda i, *_: (i, 0, 0))],
+        out_specs=[pl.BlockSpec((None, 1, LANES),
+                                lambda i, *_: (i, 0, 0))] * 3,
     )
     tok, p_draft, alt = pl.pallas_call(
         _fused_sample_kernel,
         grid_spec=grid_spec,
         out_shape=outs,
-        compiler_params=CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(jnp.asarray(temp, jnp.float32), jnp.asarray(top_k, jnp.int32),
       jnp.asarray(top_p, jnp.float32), jnp.asarray(u, jnp.float32),
       jnp.asarray(draft, jnp.int32), jnp.asarray(bias_ids, jnp.int32),
       jnp.asarray(bias_vals, jnp.float32),
-      jnp.asarray(logits, jnp.float32))
-    return tok[:, 0], p_draft[:, 0], alt[:, 0]
+      jnp.asarray(logits, jnp.float32).reshape(r, 1, v))
+    return tok[:, 0, 0], p_draft[:, 0, 0], alt[:, 0, 0]
 
 
 @jax.jit

@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 
 def _kernel(a_ref, x_ref, dt_ref, b_ref, c_ref, h0_ref, y_ref, hout_ref,
             state_ref, *, chunk: int, n_chunks: int):
@@ -111,7 +109,7 @@ def ssd_scan(x: jax.Array, dt: jax.Array, a: jax.Array, bmat: jax.Array,
             jax.ShapeDtypeStruct((b, nh, hd, ds), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((hd, ds), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a.reshape(nh, 1).astype(jnp.float32), xt, dtt, bt, ct, init_state)

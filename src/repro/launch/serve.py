@@ -1,112 +1,66 @@
-"""Serving launcher: real engine + LAPS scheduler under synthetic
-multi-turn traffic (CLI wrapper over serving.loop.ServeLoop).
+"""Serving launcher: builds a config's params and the paged packed
+``Engine``, then serves the shared request script of
+``repro.launch.drive`` through ``ServeLoop`` (short prompts, one long
+chunked prompt, a prefix-hit second turn, a few decode steps).
 
-On this CPU container, use --smoke (reduced config).  On a pod, the same
-entry point builds the production mesh and serve-rule shardings.
+The full published config is the default; ``--smoke`` selects the
+reduced one for a CPU run.
 
 Example:
-  PYTHONPATH=src python -m repro.launch.serve --arch qwen3-4b --smoke \
-      --sessions 8 --turns 3 --variant pla_full
+  PYTHONPATH=src python -m repro.launch.serve --arch qwen3-4b --smoke
 """
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
+from typing import Optional, Sequence
 
 import jax
-import numpy as np
 
 from repro.configs import get_config, get_smoke
-from repro.core import H200_QWEN32B, Variant, make_policy
+from repro.launch import drive
 from repro.models import transformer as tr
-from repro.serving import Engine, EngineConfig
-from repro.serving.loop import ServeLoop
 
 
-def main():
+def parse_args(argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-4b")
-    ap.add_argument("--smoke", action="store_true", default=True)
-    ap.add_argument("--variant", default="pla_full",
-                    choices=[v.value for v in Variant])
-    ap.add_argument("--sessions", type=int, default=6)
-    ap.add_argument("--turns", type=int, default=3)
-    ap.add_argument("--decode-steps", type=int, default=4)
-    ap.add_argument("--slo", type=float, default=2.0)
+    ap.add_argument("--smoke", action=argparse.BooleanOptionalAction,
+                    default=False,
+                    help="reduced config and sizes (default: full config)")
+    ap.add_argument("--max-len", type=int, default=None)
+    ap.add_argument("--num-pages", type=int, default=None)
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--packed", action="store_true",
-                    help="packed token-bucket stream, arena-resident (§6)")
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
+
+def main(argv: Optional[Sequence[str]] = None) -> None:
+    args = parse_args(argv)
+    cache = drive.enable_compile_cache()
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
-    params, _ = tr.init_params(cfg, jax.random.key(args.seed))
-    # --packed rides the full default stack (packed + paged pool, §12);
-    # the plain run keeps the explicit slot/dense baseline
-    engine = Engine(cfg, params, EngineConfig(
-        num_slots=max(8, args.sessions), max_len=192, chunk_tokens=32,
-        packed=args.packed, paged_kv=args.packed))
-    awd_cfg = None
-    if args.packed and engine.packed_executor is not None:
-        from repro.core.awd import AWDConfig
-        awd_cfg = AWDConfig(packed=True,
-                            token_buckets=engine.ecfg.token_buckets,
-                            packed_max_seqs=engine.packed_executor.max_seqs)
-    policy = make_policy(Variant(args.variant), H200_QWEN32B, threshold=48,
-                         chunk_tokens=32, awd_cfg=awd_cfg)
-    if engine.packed_executor is None:
-        # §3.1: capture the (L, B) executable grid at system init.  A
-        # packed-arena engine skips this — the dense grid is only its
-        # SSM/off-ladder fallback, and its warmup gathers would muddy
-        # the zero-slot-copy proof counters (§6)
-        cap = engine.executor.precapture(
-            params, engine.arena.gather, lengths=(8, 16, 32, 64),
-            depths=(1, 2, 4))
-        print(f"[serve] captured {len(engine.executor.compile_times)} "
-              f"shapes in {cap:.1f}s at init")
-    if engine.decode_executor is not None and not engine._paged:
-        # §5: compile every decode-ladder rung up front too, so no live
-        # decode tick pays a first-rung compile.  The paged engine's
-        # rungs key on bucket × P_max and compile lazily on first tick.
-        dcap = engine.decode_executor.precapture(params, engine.arena.arena)
-        print(f"[serve] captured {len(engine.decode_executor.compile_times)}"
-              f" decode rungs in {dcap:.1f}s at init")
-    if engine.packed_executor is not None and engine.ecfg.arena_prefill \
-            and not engine._paged:
-        # §6: compile every token bucket's arena-resident packed step —
-        # the hot path for every prefill/mixed/chunk tick
-        pcap = engine.packed_executor.precapture_arena(params,
-                                                      engine.arena.arena)
-        print(f"[serve] captured {len(engine.packed_executor.token_buckets)}"
-              f" packed-arena buckets in {pcap:.1f}s at init")
-    loop = ServeLoop(engine, policy, slo_ttft=args.slo)
-
-    rng = np.random.default_rng(args.seed)
+    sizes = drive.SMOKE_SIZES if args.smoke else drive.DriveSizes()
+    over = {k: v for k, v in (("max_len", args.max_len),
+                              ("num_pages", args.num_pages))
+            if v is not None}
+    sizes = dataclasses.replace(sizes, **over)
+    dev = jax.devices()[0]
+    print(f"[serve] arch={cfg.name} layers={cfg.num_layers} "
+          f"d_model={cfg.d_model} dtype={cfg.dtype} device={dev.platform}/"
+          f"{dev.device_kind} compile_cache={cache}")
     t0 = time.perf_counter()
-    for turn in range(args.turns):
-        for s in range(args.sessions):
-            if rng.random() < 0.2:
-                n = int(rng.integers(48, 96))     # long prefill
-            else:
-                n = int(rng.integers(4, 32))      # short / re-prefill
-            loop.submit(s, rng.integers(0, cfg.vocab_size, n))
-        loop.run_until_idle(max_wall=120.0)
-        for s in range(args.sessions):
-            toks = loop.decode(s, args.decode_steps)
-            if turn == args.turns - 1 and s == 0:
-                print(f"[serve] session {s} decoded: {toks}")
-    wall = time.perf_counter() - t0
-
-    rep = loop.tracker.report(wall)
-    print(f"[serve] arch={cfg.name} variant={args.variant} "
-          f"requests={rep.n} wall={wall:.1f}s")
-    print(f"[serve] mean TTFT {rep.mean_ttft * 1000:.1f} ms  "
-          f"p90 {rep.p90_ttft * 1000:.1f} ms  viol {rep.violation_rate:.3f}  "
-          f"graph-hit {rep.graph_hit_rate:.2f}")
-    print(f"[serve] engine stats: {engine.stats()}")
-    fit = engine.fit_boundary()
-    if fit:
-        print(f"[serve] fitted boundary L_m = {fit.boundary():.0f} tokens "
-              f"(fixed {fit.fixed * 1000:.2f} ms, beta {fit.beta_eff * 1e3:.3f} ms/tok)")
+    params, _ = tr.init_params(cfg, jax.random.key(args.seed))
+    jax.block_until_ready(params)
+    print(f"[serve] params built in {time.perf_counter() - t0:.1f}s")
+    res = drive.serve_script(cfg, params, sizes, seed=args.seed)
+    for rung, sec in res.compile_seconds.items():
+        print(f"[serve] compiled {rung} in {sec:.1f}s")
+    print(f"[serve] requests={res.requests} "
+          f"prefix_hit_tokens={res.prefix_hit_tokens} "
+          f"decoded_tokens={res.decoded_tokens}")
+    print(f"[serve] session {res.followup} (second turn) generated: "
+          f"{res.generated[res.followup]}")
+    print(f"[serve] engine stats: {res.engine.stats()}")
 
 
 if __name__ == "__main__":

@@ -72,33 +72,36 @@ def init_params(cfg: ModelConfig, key=None,
                 abstract: bool = False) -> Tuple[Dict, Dict]:
     """Returns (params, logical_axes) with pattern-stacked blocks.
 
-    abstract=True: ShapeDtypeStruct leaves, no allocation (dry-run)."""
+    abstract=True: ShapeDtypeStruct leaves, no allocation (dry-run).
+    Otherwise the whole init runs as ONE jitted program that draws each
+    stacked leaf directly (vmapped over the group keys), so no per-layer
+    copy lives next to its stacked copy: peak device memory is about the
+    size of the weights."""
+    shapes, axes = _build_params(cfg, None, abstract=True)
+    if abstract:
+        return shapes, axes
+    params = jax.jit(lambda k: _build_params(cfg, k, abstract=False)[0])(key)
+    return params, axes
+
+
+def _build_params(cfg: ModelConfig, key, abstract: bool) -> Tuple[Dict, Dict]:
     p = pattern_period(cfg)
     g = num_groups(cfg)
-    if abstract:
-        keys = [None] * (2 + p * g)
-    else:
-        keys = list(jax.random.split(key, 2 + p * g))
+    keys = [None] * 3 if abstract else list(jax.random.split(key, 3))
     pb = ParamBuilder(keys[0], cfg.np_dtype, abstract)
     pb.dense("embed", (cfg.padded_vocab, cfg.d_model), ("vocab", "embed"),
              scale=0.02)
+    group_keys = (None if abstract
+                  else jax.random.split(keys[2], (p, g)))
     blocks, blocks_axes = [], []
-    ki = 2
     for j in range(p):
-        per_group = []
-        axes_j = None
-        for _ in range(g):
-            lp, la = _init_one_layer(keys[ki], cfg, j, abstract)
-            per_group.append(lp)
-            axes_j = la
-            ki += 1
+        lp, axes_j = _init_one_layer(None, cfg, j, abstract=True)
         if abstract:
             stacked = jax.tree.map(
-                lambda s: jax.ShapeDtypeStruct((g,) + s.shape, s.dtype),
-                per_group[0])
+                lambda s: jax.ShapeDtypeStruct((g,) + s.shape, s.dtype), lp)
         else:
-            stacked = jax.tree.map(lambda *xs: jnp.stack(xs, axis=0),
-                                   *per_group)
+            stacked = jax.vmap(
+                lambda k, j=j: _init_one_layer(k, cfg, j)[0])(group_keys[j])
         blocks.append(stacked)
         # leading scan dim is unsharded
         blocks_axes.append(jax.tree.map(

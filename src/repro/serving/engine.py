@@ -20,6 +20,7 @@ mesh + serve sharding rules) on a TPU pod slice.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -129,11 +130,29 @@ class EngineConfig:
     fused_sampling: bool = False
 
 
+def _on_device(device: Optional[jax.Device]):
+    """Allocate on ``device`` (JAX's default placement when None)."""
+    return (contextlib.nullcontext() if device is None
+            else jax.default_device(device))
+
+
 class Engine:
-    def __init__(self, cfg: ModelConfig, params, ecfg: Optional[EngineConfig] = None):
+    def __init__(self, cfg: ModelConfig, params,
+                 ecfg: Optional[EngineConfig] = None,
+                 device: Optional[jax.Device] = None):
+        """``device`` commits the params and the KV pool to one device
+        (one-chip replicas behind a router); None keeps JAX's default
+        placement.  Every step then runs where its pool lives."""
         self.cfg = cfg
-        self.params = params
+        self.params = (params if device is None
+                       else jax.device_put(params, device))
         self.ecfg = ecfg or EngineConfig()
+        if self.ecfg.fused_sampling and kernel_ops.on_tpu():
+            raise ValueError(
+                "fused_sampling=True cannot run on a TPU: the fused "
+                "sampling kernel's inverse-CDF draw needs cumsum, which "
+                "Pallas cannot lower for the chip — set "
+                "fused_sampling=False (non-greedy rows sample on host)")
         cap = tr.arena_capability(cfg)
         self.capability = cap
         # ---- arena layout (DESIGN.md §7) ------------------------------
@@ -189,14 +208,19 @@ class Engine:
                 depth = min(self.ecfg.max_len,
                             cap.window + self._seg_margin)
                 ring_pages = -(-depth // self.ecfg.page_size)
-            self.arena = PagedKVArena(
-                cfg, num_pages, self.ecfg.page_size, self.ecfg.max_len,
-                prefix_cache=self.ecfg.prefix_cache,
-                ring_pages=ring_pages, state_slots=cap.has_ssm,
-                host_pool_bytes=self.ecfg.host_pool_bytes)
+            with _on_device(device):
+                self.arena = PagedKVArena(
+                    cfg, num_pages, self.ecfg.page_size, self.ecfg.max_len,
+                    prefix_cache=self.ecfg.prefix_cache,
+                    ring_pages=ring_pages, state_slots=cap.has_ssm,
+                    host_pool_bytes=self.ecfg.host_pool_bytes)
         else:
-            self.arena = KVArena(cfg, self.ecfg.num_slots, self.ecfg.max_len,
-                                 swa_depth=swa_depth, scratch_slot=scratch)
+            with _on_device(device):
+                self.arena = KVArena(cfg, self.ecfg.num_slots,
+                                     self.ecfg.max_len, swa_depth=swa_depth,
+                                     scratch_slot=scratch)
+        if device is not None:
+            self.arena.arena = jax.device_put(self.arena.arena, device)
         # dense gather/scatter is a valid fallback everywhere EXCEPT on
         # rolling arenas (absolute-position writes don't fit a rolling
         # slot) and paged pools (pages are scattered, shared, and have

@@ -201,18 +201,6 @@ def make_arena_decode_fn(cfg: ModelConfig) -> Callable:
     return decode_step
 
 
-def resolve_donation(donate_cache: Optional[bool]) -> bool:
-    """Effective cache-donation flag.
-
-    None → donate on TPU only (the conservative historical default).
-    An EXPLICIT True/False is always respected: jax supports buffer
-    donation on CPU too, so a caller's choice must not be silently
-    overridden (the old code dropped True on CPU without a trace)."""
-    if donate_cache is None:
-        return jax.default_backend() == "tpu"
-    return bool(donate_cache)
-
-
 class _ExecutorBase:
     """Compile-once shape cache + hit/miss + padding-efficiency stats."""
 
@@ -305,15 +293,13 @@ class _ExecutorBase:
 class BucketExecutor(_ExecutorBase):
     """The dense (L, B) bucket-grid executor (pads to captured shapes)."""
 
-    def __init__(self, cfg: ModelConfig, donate_cache: Optional[bool] = None):
+    def __init__(self, cfg: ModelConfig):
         super().__init__()
         self.cfg = cfg
-        self.donate_cache = resolve_donation(donate_cache)
         self._prefill = make_prefill_fn(cfg)
         self._decode = make_decode_fn(cfg)
-        donate = (3,) if self.donate_cache else ()
-        self._jit_prefill = jax.jit(self._prefill, donate_argnums=donate)
-        self._jit_decode = jax.jit(self._decode, donate_argnums=donate)
+        self._jit_prefill = jax.jit(self._prefill, donate_argnums=(3,))
+        self._jit_decode = jax.jit(self._decode, donate_argnums=(3,))
 
     # ---------------------------------------------------------- dispatch
     def prefill(self, params, tokens, positions, caches, sample_idx):
@@ -356,8 +342,7 @@ class PackedBucketExecutor(_ExecutorBase):
 
     def __init__(self, cfg: ModelConfig,
                  token_buckets: Tuple[int, ...] = DEFAULT_TOKEN_BUCKETS,
-                 max_seqs: int = 16,
-                 donate_cache: Optional[bool] = None):
+                 max_seqs: int = 16):
         super().__init__()
         self.capability = tr.arena_capability(cfg)
         if not self.capability.packed_ok:
@@ -378,7 +363,6 @@ class PackedBucketExecutor(_ExecutorBase):
             assert max_seqs >= 1, \
                 "scratch-slot arenas need packed max_seqs >= 2"
         self.ladder = TokenBucketLadder(token_buckets, max_seqs)
-        self.donate_cache = resolve_donation(donate_cache)
         # LEGACY gathered-cache form: whole arena slots copied out and
         # back around the step — pure-attention only (SSM state and
         # rolling SWA slots have no gathered equivalent), kept as the
@@ -388,21 +372,21 @@ class PackedBucketExecutor(_ExecutorBase):
             self._packed = make_packed_prefill_fn(cfg)
             self._jit_packed = jax.jit(
                 self._packed,
-                donate_argnums=(7,) if self.donate_cache else ())
+                donate_argnums=(7,))
         # arena-resident form (DESIGN.md §6/§7): the KV + state arenas
         # ride as an in-place argument (donated) instead of gathered
         # cache rows; per-layer routing from the capability descriptor
         self._packed_arena = make_packed_arena_fn(cfg)
         self._jit_packed_arena = jax.jit(
             self._packed_arena,
-            donate_argnums=(8,) if self.donate_cache else ())
+            donate_argnums=(8,))
         # paged form (DESIGN.md §8/§12): per-block page table instead of
         # a per-segment slot — every packed_ok config (windowed layers
         # walk a ring table, SSM layers step per-session state pages)
         self._packed_paged = make_packed_paged_fn(cfg)
         self._jit_packed_paged = jax.jit(
             self._packed_paged,
-            donate_argnums=(9,) if self.donate_cache else ())
+            donate_argnums=(9,))
         # speculative verification forms (DESIGN.md §10): the SAME
         # packed dispatch with an L-per-segment logits gather.  Their
         # compile cache is keyed on (token bucket, L) via the
@@ -410,11 +394,11 @@ class PackedBucketExecutor(_ExecutorBase):
         self._verify_arena = make_packed_verify_arena_fn(cfg)
         self._jit_verify_arena = jax.jit(
             self._verify_arena,
-            donate_argnums=(8,) if self.donate_cache else ())
+            donate_argnums=(8,))
         self._verify_paged = make_packed_verify_paged_fn(cfg)
         self._jit_verify_paged = jax.jit(
             self._verify_paged,
-            donate_argnums=(9,) if self.donate_cache else ())
+            donate_argnums=(9,))
         # continuous-batching counters: a mixed step fuses decode rows
         # into the same packed stream (and the SAME compiled executable —
         # the shape key is (token bucket, max_seqs), not the segment mix)
@@ -551,44 +535,21 @@ class PackedBucketExecutor(_ExecutorBase):
         exe = self._get("verify_paged", self._jit_verify_paged, args)
         return exe(*args)
 
-    def precapture(self, params, arena_gather) -> float:
-        """Compile every token bucket at init — |token_buckets| shapes
-        total, vs |L|×|B| for the dense grid."""
-        t0 = time.perf_counter()
-        b = self.max_seqs
-        caches = arena_gather(list(range(b)))
-        for t in self.token_buckets:
-            tokens = jnp.zeros((t,), jnp.int32)
-            positions = jnp.zeros((t,), jnp.int32)
-            seg_ids = jnp.zeros((t,), jnp.int32)
-            cu = jnp.zeros((b + 1,), jnp.int32)
-            off = jnp.zeros((b,), jnp.int32)
-            kvl = jnp.zeros((b,), jnp.int32)
-            last = jnp.zeros((b,), jnp.int32)
-            self._get("packed_prefill", self._jit_packed,
-                      (params, tokens, positions, seg_ids, cu, off, kvl,
-                       caches, last))
-        return time.perf_counter() - t0
-
-    def precapture_arena(self, params, arena) -> float:
-        """Compile every token bucket's arena-resident step at init —
-        |token_buckets| shapes total.  Lower + compile only; the arena
-        is never executed against (nor donated away)."""
-        t0 = time.perf_counter()
+    def precapture_paged(self, params, arena, p_max: int) -> Dict[int, float]:
+        """Compile every token bucket's paged step at init (|token_buckets|
+        shapes; the page pool and P_max are constants).  Lower + compile
+        only — the pool is never executed against nor donated away.
+        Returns compile seconds per token bucket."""
         b = self.stream_rows
+        out: Dict[int, float] = {}
         for t in self.token_buckets:
-            tokens = jnp.zeros((t,), jnp.int32)
-            positions = jnp.zeros((t,), jnp.int32)
-            seg_slots = jnp.zeros((t,), jnp.int32)
-            slot_map = jnp.zeros((b,), jnp.int32)
-            cu = jnp.zeros((b + 1,), jnp.int32)
-            off = jnp.zeros((b,), jnp.int32)
-            kvl = jnp.zeros((b,), jnp.int32)
-            last = jnp.zeros((b,), jnp.int32)
-            self._get("packed_arena", self._jit_packed_arena,
-                      (params, tokens, positions, seg_slots, slot_map, cu,
-                       off, kvl, arena, last))
-        return time.perf_counter() - t0
+            t0 = time.perf_counter()
+            self._get("packed_paged", self._jit_packed_paged,
+                      (params, _ints(t), _ints(t), _ints(t), _ints(t),
+                       _ints(b, p_max), _ints(b + 1), _ints(b), _ints(b),
+                       arena, _ints(b), _ints(b)))
+            out[t] = time.perf_counter() - t0
+        return out
 
 
 class DecodeBucketExecutor(_ExecutorBase):
@@ -611,8 +572,7 @@ class DecodeBucketExecutor(_ExecutorBase):
 
     def __init__(self, cfg: ModelConfig,
                  decode_buckets: Tuple[int, ...] = DEFAULT_DECODE_BUCKETS,
-                 max_seqs: Optional[int] = None,
-                 donate_cache: Optional[bool] = None):
+                 max_seqs: Optional[int] = None):
         super().__init__()
         self.capability = tr.arena_capability(cfg)
         if not self.capability.packed_ok:
@@ -621,17 +581,16 @@ class DecodeBucketExecutor(_ExecutorBase):
                 "decoder (encoder-only models have no decode loop)")
         self.cfg = cfg
         self.ladder = DecodeBucketLadder(decode_buckets, max_seqs)
-        self.donate_cache = resolve_donation(donate_cache)
         self._decode = make_arena_decode_fn(cfg)
         self._jit_decode = jax.jit(
-            self._decode, donate_argnums=(5,) if self.donate_cache else ())
+            self._decode, donate_argnums=(5,))
         # paged form (DESIGN.md §8/§12): every packed_ok config —
         # windowed layers walk a ring table, SSM layers step their
         # per-session state page through state_map
         self._decode_paged = make_paged_decode_fn(cfg)
         self._jit_decode_paged = jax.jit(
             self._decode_paged,
-            donate_argnums=(7,) if self.donate_cache else ())
+            donate_argnums=(7,))
 
     # ------------------------------------------------------------ lookup
     @property
@@ -661,18 +620,23 @@ class DecodeBucketExecutor(_ExecutorBase):
         exe = self._get("paged_decode", self._jit_decode_paged, args)
         return exe(*args)
 
-    def precapture(self, params, arena) -> float:
-        """Compile every decode rung at init — |ladder| shapes total, vs
-        one per live session count on the dense path.  Lower + compile
-        only; the arena is never executed against (nor donated away)."""
-        t0 = time.perf_counter()
+    def precapture_paged(self, params, arena, p_max: int) -> Dict[int, float]:
+        """Compile every decode rung's paged step at init — |ladder|
+        shapes, vs one per live session count on the dense path.  Lower
+        + compile only.  Returns compile seconds per rung."""
+        out: Dict[int, float] = {}
         for b in self.decode_buckets:
-            tokens = jnp.zeros((b,), jnp.int32)
-            rows = jnp.zeros((b,), jnp.int32)
-            lens = jnp.ones((b,), jnp.int32)
-            self._get("arena_decode", self._jit_decode,
-                      (params, tokens, rows, rows, lens, arena))
-        return time.perf_counter() - t0
+            t0 = time.perf_counter()
+            self._get("paged_decode", self._jit_decode_paged,
+                      (params, _ints(b), _ints(b), _ints(b), _ints(b),
+                       _ints(b, p_max), _ints(b), arena, _ints(b)))
+            out[b] = time.perf_counter() - t0
+        return out
+
+
+def _ints(*shape: int) -> jax.ShapeDtypeStruct:
+    """An int32 operand shape: rungs compile from shapes, never data."""
+    return jax.ShapeDtypeStruct(shape, jnp.int32)
 
 
 __all__ = ["BucketExecutor", "PackedBucketExecutor", "DecodeBucketExecutor",
@@ -681,4 +645,4 @@ __all__ = ["BucketExecutor", "PackedBucketExecutor", "DecodeBucketExecutor",
            "make_packed_arena_fn", "make_packed_paged_fn",
            "make_packed_verify_arena_fn", "make_packed_verify_paged_fn",
            "make_decode_fn", "make_arena_decode_fn",
-           "make_paged_decode_fn", "resolve_donation"]
+           "make_paged_decode_fn"]

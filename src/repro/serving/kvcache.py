@@ -154,7 +154,8 @@ class KVArena:
         slot = self.alloc(session)
         if n_tokens:
             self.arena = jax.tree.map(
-                lambda a, b: a.at[:, slot, :n_tokens].set(b.astype(a.dtype)),
+                lambda a, b: a.at[:, slot, :n_tokens].set(
+                    jax.device_put(b, a.sharding).astype(a.dtype)),
                 self.arena, kv)
         self.set_length(session, n_tokens)
         return slot
@@ -287,7 +288,7 @@ class PagedKVArena:
 
     Layout per layer-pattern position: k/v ``(G, N_pages + 1, page_size,
     Hkv, D)`` — init_cache's batch axis becomes the PAGE axis, so the
-    paged kernels read ``(1, page_size, 1, D)`` blocks exactly like the
+    paged kernels read ``(1, page_size, Hkv, D)`` blocks exactly like the
     slot kernels read arena blocks.  Page ``N_pages`` is the reserved
     SCRATCH page (the §6/§7 scratch-row/slot invariant at page
     granularity): it is never allocated, never indexed, and pad stream
@@ -918,8 +919,11 @@ class PagedKVArena:
         pages = [self._alloc_page() for _ in range(n_pages)]
         if self.arena is not None and kv is not None and pages:
             idx = jnp.asarray(pages, jnp.int32)
+            # the export may live on a peer engine's device: place it on
+            # this pool's own device first (a device-to-device copy)
             self.arena = jax.tree.map(
-                lambda a, b: a.at[:, idx].set(b[:, skip:].astype(a.dtype)),
+                lambda a, b: a.at[:, idx].set(
+                    jax.device_put(b[:, skip:], a.sharding).astype(a.dtype)),
                 self.arena, kv)
         self._pages[session].extend(pages)
         self._tokens[session].extend(int(t) for t in token_ids[h:n_tokens])

@@ -12,7 +12,6 @@ from repro.core.buckets import BucketGrid, TokenBucketLadder
 from repro.core.request import Request
 from repro.models import transformer as tr
 from repro.serving import Engine, EngineConfig, PackedBucketExecutor
-from repro.serving.executor import resolve_donation
 
 KEY = jax.random.key(3)
 
@@ -199,29 +198,25 @@ def test_awd_packed_profitability_guard():
 
 
 def test_resolve_donation_respects_explicit_flag():
-    # default: backend heuristic (CPU in tests → False)
-    assert resolve_donation(None) == (jax.default_backend() == "tpu")
-    # explicit choice wins on every backend — never silently dropped
-    assert resolve_donation(True) is True
-    assert resolve_donation(False) is False
+    # donation is unconditional: every executor donates its cache
+    # argument on every backend, so the CPU tests run the chip's path
+    import repro.serving.executor as ex_mod
+    assert not hasattr(ex_mod, "resolve_donation")
+    for cls in (ex_mod.BucketExecutor, ex_mod.PackedBucketExecutor,
+                ex_mod.DecodeBucketExecutor):
+        assert "donate_cache" not in cls.__init__.__code__.co_varnames
 
 
 def test_executor_donation_applied_on_cpu(qwen):
-    """donate_cache=True must actually donate (the old code silently
-    disabled it off-TPU): the input cache buffer is invalidated."""
+    """The cache argument must actually be donated off-TPU too: the
+    input cache buffer is invalidated by the step."""
     cfg, params = qwen
     from repro.serving.executor import BucketExecutor
-    ex = BucketExecutor(cfg, donate_cache=True)
-    assert ex.donate_cache is True
+    ex = BucketExecutor(cfg)
     caches = tr.init_cache(cfg, 1, 16)
     tokens = jnp.zeros((1, 4), jnp.int32)
     positions = jnp.tile(jnp.arange(4), (1, 1))
-    ex.prefill(params, tokens, positions, caches, jnp.asarray([3]))
-    leaf = jax.tree.leaves(caches)[0]
-    assert leaf.is_deleted()
-
-    ex2 = BucketExecutor(cfg, donate_cache=False)
-    assert ex2.donate_cache is False
-    caches2 = tr.init_cache(cfg, 1, 16)
-    ex2.prefill(params, tokens, positions, caches2, jnp.asarray([3]))
-    assert not jax.tree.leaves(caches2)[0].is_deleted()
+    _, new_caches = ex.prefill(params, tokens, positions, caches,
+                               jnp.asarray([3]))
+    assert jax.tree.leaves(caches)[0].is_deleted()
+    assert not jax.tree.leaves(new_caches)[0].is_deleted()
