@@ -223,12 +223,14 @@ def _arena_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
 def _arena_call(kv_map, table, lengths, q, k, v, *, window: Optional[int],
                 depth: int, block_k: int, n_kv_blocks: int,
-                n_phys_blocks: int, interpret: bool) -> jax.Array:
+                n_phys_blocks: int, interpret: bool, name: str
+                ) -> jax.Array:
     """Shared pallas_call of the arena and paged forms.  Grid = (row, kv
     block); each program holds every query head of its row and reads
     (1, block_k, Hkv, D) kv blocks, whose two trailing dims are the
     pool's own — the TPU tiling rule for a block's last two dims — so
-    one DMA per block serves all heads."""
+    one DMA per block serves all heads.  ``name`` is the kernel's name
+    in a profile, that of the public form that launched it."""
     b, hq, d = q.shape
     hkv = k.shape[2]
     rep = hq // hkv
@@ -260,6 +262,7 @@ def _arena_call(kv_map, table, lengths, q, k, v, *, window: Optional[int],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name=name,
     )(table.astype(jnp.int32), lengths.astype(jnp.int32), qg, k, v)
     return out.reshape(b, hq, d)
 
@@ -319,7 +322,8 @@ def decode_attn_paged(q: jax.Array, k: jax.Array, v: jax.Array,
 
     return _arena_call(kv_map, page_table, lengths, q, k, v, window=window,
                        depth=depth, block_k=ps, n_kv_blocks=nk_iter,
-                       n_phys_blocks=nk, interpret=interpret)
+                       n_phys_blocks=nk, interpret=interpret,
+                       name="decode_attn_paged")
 
 
 @functools.partial(jax.jit, static_argnames=("window", "block_k",
@@ -374,4 +378,5 @@ def decode_attn_arena(q: jax.Array, k: jax.Array, v: jax.Array,
 
     return _arena_call(kv_map, slot_map, lengths, q, k, v, window=window,
                        depth=s, block_k=block_k, n_kv_blocks=nk_iter,
-                       n_phys_blocks=nk, interpret=interpret)
+                       n_phys_blocks=nk, interpret=interpret,
+                       name="decode_attn_arena")

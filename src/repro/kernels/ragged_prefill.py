@@ -320,12 +320,14 @@ def _arena_kernel(tbl_ref, cu_ref, off_ref, len_ref, q_ref, k_ref, v_ref,
 def _arena_call(kv_map, table, cu_seqlens, q_offsets, kv_lengths, q, k, v,
                 *, causal: bool, window: Optional[int], depth: int,
                 block_q: int, block_k: int, n_kv_blocks: int,
-                interpret: bool) -> jax.Array:
+                interpret: bool, name: str) -> jax.Array:
     """Shared pallas_call of the arena and paged forms.  Grid = (q
     block, segment, kv block); each program holds every query head of
     its q block and reads (1, block_k, Hkv, D) kv blocks, whose two
     trailing dims are the pool's own — the TPU tiling rule for a block's
-    last two dims — so one DMA per block serves all heads."""
+    last two dims — so one DMA per block serves all heads.  ``name`` is
+    the kernel's name in a profile, that of the public form that
+    launched it."""
     t, hq, d = q.shape
     hkv = k.shape[2]
     b = table.shape[0]
@@ -363,6 +365,7 @@ def _arena_call(kv_map, table, cu_seqlens, q_offsets, kv_lengths, q, k, v,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
+        name=name,
     )(table.astype(jnp.int32), cu_seqlens.astype(jnp.int32),
       q_offsets.astype(jnp.int32), kv_lengths.astype(jnp.int32), qt, k, v)
     return jnp.moveaxis(out[:, :t], 0, 1)
@@ -437,7 +440,8 @@ def ragged_prefill_paged(q: jax.Array, k: jax.Array, v: jax.Array,
     return _arena_call(kv_map, page_table, cu_seqlens, q_offsets,
                        kv_lengths, q, k, v, causal=causal, window=window,
                        depth=ps * p_max, block_q=block_q, block_k=ps,
-                       n_kv_blocks=p_max, interpret=interpret)
+                       n_kv_blocks=p_max, interpret=interpret,
+                       name="ragged_prefill_paged")
 
 
 @functools.partial(
@@ -501,4 +505,5 @@ def ragged_prefill_arena(q: jax.Array, k: jax.Array, v: jax.Array,
     return _arena_call(kv_map, slot_map, cu_seqlens, q_offsets, kv_lengths,
                        q, k, v, causal=causal, window=window, depth=s,
                        block_q=block_q, block_k=block_k,
-                       n_kv_blocks=s // block_k, interpret=interpret)
+                       n_kv_blocks=s // block_k, interpret=interpret,
+                       name="ragged_prefill_arena")

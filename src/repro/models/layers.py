@@ -430,8 +430,9 @@ def packed_paged_attention_layer(p: Dict, x: jax.Array, *, cfg,
     q = apply_rope(q[None], positions[None], cfg.rope_theta)[0]
     k = apply_rope(k[None], positions[None], cfg.rope_theta)[0]
 
-    ck = kv[0].at[token_pages, token_offs].set(k.astype(kv[0].dtype))
-    cv = kv[1].at[token_pages, token_offs].set(v.astype(kv[1].dtype))
+    with jax.named_scope("laps/kv_write"):
+        ck = kv[0].at[token_pages, token_offs].set(k.astype(kv[0].dtype))
+        cv = kv[1].at[token_pages, token_offs].set(v.astype(kv[1].dtype))
 
     out = kernel_ops.ragged_mha_paged(q, ck, cv, page_table, cu_seqlens,
                                       q_offsets, kv_lengths,
@@ -479,8 +480,9 @@ def paged_decode_layer(p: Dict, x: jax.Array, *, cfg,
     q = apply_rope(q[:, None], positions[:, None], cfg.rope_theta)[:, 0]
     k = apply_rope(k[:, None], positions[:, None], cfg.rope_theta)[:, 0]
 
-    ck = kv[0].at[write_pages, write_offs].set(k.astype(kv[0].dtype))
-    cv = kv[1].at[write_pages, write_offs].set(v.astype(kv[1].dtype))
+    with jax.named_scope("laps/kv_write"):
+        ck = kv[0].at[write_pages, write_offs].set(k.astype(kv[0].dtype))
+        cv = kv[1].at[write_pages, write_offs].set(v.astype(kv[1].dtype))
 
     out = kernel_ops.decode_paged(q, ck, cv, page_table, kv_lengths,
                                   window=window)

@@ -355,14 +355,15 @@ def _lm_head_logits(params: Dict, cfg: ModelConfig,
     by every serving step that emits one logit row per sequence — the
     packed, packed-arena, and arena-decode paths must never diverge
     here, they are parity-tested against each other."""
-    logits = x @ params["lm_head"]
-    logits = constrain(logits, "batch", "vocab")
-    vpad = cfg.padded_vocab - cfg.vocab_size
-    if vpad:
-        neg = jnp.concatenate(
-            [jnp.zeros((cfg.vocab_size,), logits.dtype),
-             jnp.full((vpad,), -1e9, logits.dtype)])
-        logits = logits + neg
+    with jax.named_scope("laps/lm_head"):
+        logits = x @ params["lm_head"]
+        logits = constrain(logits, "batch", "vocab")
+        vpad = cfg.padded_vocab - cfg.vocab_size
+        if vpad:
+            neg = jnp.concatenate(
+                [jnp.zeros((cfg.vocab_size,), logits.dtype),
+                 jnp.full((vpad,), -1e9, logits.dtype)])
+            logits = logits + neg
     return logits
 
 
@@ -379,27 +380,34 @@ def _scan_serving_stack(params: Dict, cfg: ModelConfig, tokens: jax.Array,
     {"ssm", "conv"}; everything else — including the cache
     constrain_tree pinning — is identical across the paths and lives
     exactly once.  Returns (final-normed activations, new caches)."""
-    x = jnp.take(params["embed"], tokens, axis=0)
+    with jax.named_scope("laps/embed"):
+        x = jnp.take(params["embed"], tokens, axis=0)
     p = pattern_period(cfg)
     cache_axes = cache_logical_axes(cfg)
 
     def body(carry, lps):
         x, aux, cs_all, g = carry
         for j in range(p):
-            cache_j = jax.tree.map(
-                lambda a: jax.lax.dynamic_index_in_dim(
-                    a, g, 0, keepdims=False), cs_all[j])
-            h = rms_norm(x, lps[j]["ln1"], cfg.norm_eps)
-            mix, nc = mix_fn(j, lps[j]["mixer"], h, cache_j)
-            x = x + mix
+            # the scan carries every layer's pool: laps/kv_carry names
+            # the slice of this layer's pool out of it and back
+            with jax.named_scope("laps/kv_carry"):
+                cache_j = jax.tree.map(
+                    lambda a: jax.lax.dynamic_index_in_dim(
+                        a, g, 0, keepdims=False), cs_all[j])
+            with jax.named_scope("laps/attn"):
+                h = rms_norm(x, lps[j]["ln1"], cfg.norm_eps)
+                mix, nc = mix_fn(j, lps[j]["mixer"], h, cache_j)
+                x = x + mix
             if cfg.family != "ssm":
-                x2, a = _ffn(cfg, j, lps[j], x[None])
+                with jax.named_scope("laps/mlp"):
+                    x2, a = _ffn(cfg, j, lps[j], x[None])
                 x = x2[0]
                 aux = aux + a
-            full = jax.tree.map(
-                lambda fa, u: jax.lax.dynamic_update_index_in_dim(
-                    fa, u.astype(fa.dtype), g, 0), cs_all[j], nc)
-            cs_all[j] = constrain_tree(full, cache_axes[j])
+            with jax.named_scope("laps/kv_carry"):
+                full = jax.tree.map(
+                    lambda fa, u: jax.lax.dynamic_update_index_in_dim(
+                        fa, u.astype(fa.dtype), g, 0), cs_all[j], nc)
+                cs_all[j] = constrain_tree(full, cache_axes[j])
         return (x, aux, cs_all, g + 1), None
 
     zero = jnp.zeros((), jnp.float32)

@@ -35,6 +35,7 @@ from repro.core.request import Request
 from repro.core.routing import EngineView, LengthAwareRouter, RouteRequest, \
     Router
 from repro.core.slo import SLOReport, SLOTracker
+from repro.serving import trace
 from repro.serving.loop import PendingRequest, ServeLoop
 from repro.serving.sampling import SamplingParams
 from repro.sim.costmodel import CostModel, H200_32B
@@ -120,6 +121,11 @@ class ServeCluster:
                 free_slots=free))
         return out
 
+    def _route(self, meta: RouteRequest, **kw) -> int:
+        """The router's choice of engine over the live views."""
+        with trace.span("laps.router.route", tokens=meta.new_tokens):
+            return self.router.route(meta, self.views(), **kw)
+
     def alive_engines(self) -> List[int]:
         return [i for i, h in enumerate(self.health) if h != "dead"]
 
@@ -152,7 +158,7 @@ class ServeCluster:
         meta = RouteRequest(new_tokens=len(tokens),
                             decode_tokens=decode_tokens, session=session)
         if fresh:
-            eid = self.router.route(meta, self.views())
+            eid = self._route(meta)
             self._home[session] = eid
         r = self.loops[eid].submit(session, tokens,
                                    decode_tokens=decode_tokens,
@@ -226,8 +232,7 @@ class ServeCluster:
             meta = RouteRequest(new_tokens=len(tokens),
                                 decode_tokens=w.decode_tokens,
                                 session=session)
-            new_eid = self.router.route(meta, self.views(),
-                                        exclude=frozenset({eid}))
+            new_eid = self._route(meta, exclude=frozenset({eid}))
             self._home[session] = new_eid
             r2 = self.loops[new_eid].submit(
                 session, tokens, decode_tokens=w.decode_tokens,
@@ -383,7 +388,7 @@ class ServeCluster:
                 meta = RouteRequest(new_tokens=len(tokens),
                                     decode_tokens=w.decode_tokens,
                                     session=session)
-                home = self.router.route(meta, self.views())
+                home = self._route(meta)
                 self._home[session] = home
             self._resubmit(home, w)
             self.rerouted_requests += 1
@@ -413,7 +418,7 @@ class ServeCluster:
         budget = lp.active_decodes.get(session, 0)
         meta = RouteRequest(new_tokens=len(cache), decode_tokens=budget,
                             session=session)
-        dst = self.router.route(meta, self.views())
+        dst = self._route(meta)
         self.loops[dst].restore_session(
             session, cache,
             pending=lp._cache_pending.get(session),
@@ -439,7 +444,7 @@ class ServeCluster:
                 meta = RouteRequest(new_tokens=len(tokens),
                                     decode_tokens=w.decode_tokens,
                                     session=session)
-                eid = self.router.route(meta, self.views())
+                eid = self._route(meta)
                 self._home[session] = eid
             self._resubmit(eid, w)
             self.loops[eid].tracker.note_retried()

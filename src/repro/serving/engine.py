@@ -37,6 +37,7 @@ from repro.models import transformer as tr
 from repro.models.config import ModelConfig
 from repro.serving import packing
 from repro.serving import sampling as sampling_mod
+from repro.serving import trace
 from repro.serving.executor import (BucketExecutor, DecodeBucketExecutor,
                                     PackedBucketExecutor)
 from repro.serving.kvcache import KVArena, PagedKVArena
@@ -537,7 +538,8 @@ class Engine:
                                          self._rngs)
 
     def _tokens_from_step(self, sessions: Sequence[int], logits_dev,
-                          ids_dev) -> Tuple[np.ndarray, Optional[np.ndarray]]:
+                          ids_dev, where: str
+                          ) -> Tuple[np.ndarray, Optional[np.ndarray]]:
         """Sample one token per session from an arena step's outputs.
 
         The executors return the on-device greedy argmax next to the
@@ -547,18 +549,33 @@ class Engine:
         Steps with sampling options (or the default logits-keeping
         config) ship the rows and sample on host as before.  Returns
         (tokens (n,), logits_np or None).
+
+        Traced, the work shows as the ``wait``, ``fetch`` and ``sample``
+        children of span ``where``; ``wait`` blocks on the device only
+        while the tracer is on, so that ``fetch`` times the copy alone.
         """
         n = len(sessions)
         all_greedy = all(s not in self.sampling for s in sessions)
-        if all_greedy and not self.ecfg.keep_last_logits:
-            self.fused_greedy_steps += 1
-            return np.asarray(ids_dev)[:n].astype(np.int64), None
-        if (self.ecfg.fused_sampling and not self.ecfg.keep_last_logits
-                and self._fused_bias_ok(sessions)):
-            return self._fused_sample_rows(sessions, logits_dev), None
-        logits_np = np.asarray(logits_dev)
-        self.logits_rows_shipped += int(logits_np.shape[0])
-        return self._sample_rows(sessions, logits_np[:n]), logits_np
+        greedy_ids = all_greedy and not self.ecfg.keep_last_logits
+        fused = (not greedy_ids and self.ecfg.fused_sampling
+                 and not self.ecfg.keep_last_logits
+                 and self._fused_bias_ok(sessions))
+        src = ids_dev if greedy_ids else logits_dev
+        if trace.enabled():
+            with trace.span(where + ".wait"):
+                jax.block_until_ready(src)
+        if fused:
+            with trace.span(where + ".sample"):
+                return self._fused_sample_rows(sessions, logits_dev), None
+        with trace.span(where + ".fetch"):
+            host = np.asarray(src)
+        trace.count("laps.engine.d2h_bytes", host.nbytes)
+        with trace.span(where + ".sample"):
+            if greedy_ids:
+                self.fused_greedy_steps += 1
+                return host[:n].astype(np.int64), None
+            self.logits_rows_shipped += int(host.shape[0])
+            return self._sample_rows(sessions, host[:n]), host
 
     def _fused_bias_ok(self, sessions: Sequence[int]) -> bool:
         """The fused sampling kernel carries MAX_BIAS bias slots per
@@ -600,7 +617,9 @@ class Engine:
         tok, _, _ = kernel_ops.fused_sample(logits_dev, temp, topk, topp,
                                             bias_ids, bias_vals, u, draft)
         self.fused_sample_steps += 1
-        return np.asarray(tok)[:n].astype(np.int64)
+        tok = np.asarray(tok)
+        trace.count("laps.engine.d2h_bytes", tok.nbytes)
+        return tok[:n].astype(np.int64)
 
     def _note_dense(self, kind: str, cause: str) -> None:
         key = (kind, cause)
@@ -896,65 +915,74 @@ class Engine:
         pad_slot = self.arena.scratch if self.arena.scratch is not None \
             else slots[0]
         all_slots = slots + [pad_slot] * (b_max - n)
-        stream = packing.assemble_mixed_stream(
-            segments, bucket, b_max, park_position=self.arena.max_len - 1,
-            pad_token=self.ecfg.pad_token)
-        sessions = [seg.session for seg in segments]
+        n_tok = sum(seg.length for seg in segments)
+        n_d = sum(seg.length for seg in segments if seg.kind == "decode")
+        with trace.span("laps.engine.packed", segments=n, tokens=n_tok,
+                        bucket=bucket, decode_rows=n_d):
+            stream = packing.assemble_mixed_stream(
+                segments, bucket, b_max, park_position=self.arena.max_len - 1,
+                pad_token=self.ecfg.pad_token)
+            sessions = [seg.session for seg in segments]
 
-        if self.ecfg.arena_prefill:
-            slot_map = np.asarray(all_slots, np.int32)
-            seg_slots = slot_map[stream.seg_ids]   # per-token arena slot
-            t0 = time.perf_counter()
-            last, ids, new_arena = px.mixed_step_arena(
-                self.params, jnp.asarray(stream.tokens),
-                jnp.asarray(stream.positions), jnp.asarray(seg_slots),
-                jnp.asarray(slot_map), jnp.asarray(stream.cu_seqlens),
-                jnp.asarray(stream.q_offsets),
-                jnp.asarray(stream.kv_lengths), self.arena.arena,
-                jnp.asarray(stream.last_idx), n_decode=stream.decode_tokens)
+            if self.ecfg.arena_prefill:
+                slot_map = np.asarray(all_slots, np.int32)
+                seg_slots = slot_map[stream.seg_ids]  # per-token arena slot
+                t0 = time.perf_counter()
+                last, ids, new_arena = px.mixed_step_arena(
+                    self.params, jnp.asarray(stream.tokens),
+                    jnp.asarray(stream.positions), jnp.asarray(seg_slots),
+                    jnp.asarray(slot_map), jnp.asarray(stream.cu_seqlens),
+                    jnp.asarray(stream.q_offsets),
+                    jnp.asarray(stream.kv_lengths), self.arena.arena,
+                    jnp.asarray(stream.last_idx),
+                    n_decode=stream.decode_tokens)
 
-            def writeback():
-                self.arena.replace(new_arena)
-        else:
-            ids = None
-            caches = self.arena.gather(all_slots)
-            t0 = time.perf_counter()
-            last, new_caches = px.mixed_step(
-                self.params, jnp.asarray(stream.tokens),
-                jnp.asarray(stream.positions), jnp.asarray(stream.seg_ids),
-                jnp.asarray(stream.cu_seqlens),
-                jnp.asarray(stream.q_offsets),
-                jnp.asarray(stream.kv_lengths), caches,
-                jnp.asarray(stream.last_idx), n_decode=stream.decode_tokens)
+                def writeback():
+                    self.arena.replace(new_arena)
+            else:
+                ids = None
+                caches = self.arena.gather(all_slots)
+                t0 = time.perf_counter()
+                last, new_caches = px.mixed_step(
+                    self.params, jnp.asarray(stream.tokens),
+                    jnp.asarray(stream.positions), jnp.asarray(stream.seg_ids),
+                    jnp.asarray(stream.cu_seqlens),
+                    jnp.asarray(stream.q_offsets),
+                    jnp.asarray(stream.kv_lengths), caches,
+                    jnp.asarray(stream.last_idx),
+                    n_decode=stream.decode_tokens)
 
-            def writeback():
-                self.arena.scatter(slots, jax.tree.map(
-                    lambda a: a[:, :n], new_caches))
-        if ids is not None:
-            toks, last_np = self._tokens_from_step(sessions, last, ids)
-        else:
-            last_np = np.asarray(last)
-            self.logits_rows_shipped += int(last_np.shape[0])
-            toks = self._sample_rows(sessions, last_np)
-        elapsed = time.perf_counter() - t0
-        px.note_padding(stream.total_tokens, bucket)
-        writeback()
-        out: Dict[int, int] = {}
-        for i, seg in enumerate(segments):
-            self.arena.set_length(seg.session, seg.history + seg.length)
-            if self.draft is not None:
-                if seg.kind == "decode":
-                    # keep the draft's view of the cached stream in sync
-                    # on non-speculative ticks too
-                    self.draft.observe(seg.session, [int(seg.tokens[0])])
-                else:
-                    # prompt/chunk tokens seed the draft's history
-                    self.draft.observe(seg.session,
-                                       [int(t) for t in seg.tokens],
-                                       prompt=True)
-            out[seg.session] = int(toks[i])
-            if last_np is not None:
-                self.last_logits[seg.session] = last_np[i]
+                def writeback():
+                    self.arena.scatter(slots, jax.tree.map(
+                        lambda a: a[:, :n], new_caches))
+            if ids is not None:
+                toks, last_np = self._tokens_from_step(sessions, last, ids,
+                                                       "laps.engine.packed")
+            else:
+                last_np = np.asarray(last)
+                self.logits_rows_shipped += int(last_np.shape[0])
+                toks = self._sample_rows(sessions, last_np)
+            elapsed = time.perf_counter() - t0
+            px.note_padding(stream.total_tokens, bucket)
+            writeback()
+            out: Dict[int, int] = {}
+            for i, seg in enumerate(segments):
+                self.arena.set_length(seg.session,
+                                      seg.history + seg.length)
+                if self.draft is not None:
+                    if seg.kind == "decode":
+                        # keep the draft's view of the cached stream in
+                        # sync on non-speculative ticks too
+                        self.draft.observe(seg.session,
+                                           [int(seg.tokens[0])])
+                    else:
+                        # prompt/chunk tokens seed the draft's history
+                        self.draft.observe(seg.session,
+                                           [int(t) for t in seg.tokens],
+                                           prompt=True)
+                out[seg.session] = int(toks[i])
+                if last_np is not None:
+                    self.last_logits[seg.session] = last_np[i]
         if self.ecfg.measure:
             # only prefill work feeds the (T, L, H) boundary fit — decode
             # rows are priced by the decode model, not T(L, H)
@@ -986,58 +1014,68 @@ class Engine:
         ps = ar.page_size
         n = len(segments)
         b_max = px.stream_rows
-        stream = packing.assemble_mixed_stream(
-            segments, bucket, b_max, park_position=ar.max_len - 1,
-            pad_token=self.ecfg.pad_token)
         sessions = [seg.session for seg in segments]
+        n_tok = sum(seg.length for seg in segments)
+        n_d = sum(seg.length for seg in segments if seg.kind == "decode")
+        with trace.span("laps.engine.packed", segments=n, tokens=n_tok,
+                        bucket=bucket, decode_rows=n_d):
+            with trace.span("laps.engine.packed.assemble"):
+                stream = packing.assemble_mixed_stream(
+                    segments, bucket, b_max, park_position=ar.max_len - 1,
+                    pad_token=self.ecfg.pad_token)
+                ring = ar.ring_pages
+                page_table = np.full((b_max, ar.max_pages_per_seq),
+                                     ar.scratch, np.int32)
+                token_pages = np.full(bucket, ar.scratch, np.int32)
+                token_offs = np.full(bucket, ps - 1, np.int32)
+                state_map = np.full(b_max, ar.scratch, np.int32)
+                cu = stream.cu_seqlens
+                for i, seg in enumerate(segments):
+                    pages = ar.prepare_extend(seg.session, seg.length)
+                    page_table[i, :len(pages)] = pages
+                    pos = stream.positions[cu[i]:cu[i + 1]]
+                    pt = np.asarray(pages, np.int32)
+                    # ring tables (§12): position p lives on ring page
+                    # (p // ps) % n_ring — the host-side half of the §7
+                    # rolling reconstruction; the kernel recovers kpos
+                    # from the slot
+                    pidx = pos // ps if ring is None else (pos // ps) % ring
+                    token_pages[cu[i]:cu[i + 1]] = pt[pidx]
+                    token_offs[cu[i]:cu[i + 1]] = pos % ps
+                    if ar.state_slots:
+                        state_map[i] = ar.state_pages[seg.session]
 
-        ring = ar.ring_pages
-        page_table = np.full((b_max, ar.max_pages_per_seq), ar.scratch,
-                             np.int32)
-        token_pages = np.full(bucket, ar.scratch, np.int32)
-        token_offs = np.full(bucket, ps - 1, np.int32)
-        state_map = np.full(b_max, ar.scratch, np.int32)
-        cu = stream.cu_seqlens
-        for i, seg in enumerate(segments):
-            pages = ar.prepare_extend(seg.session, seg.length)
-            page_table[i, :len(pages)] = pages
-            pos = stream.positions[cu[i]:cu[i + 1]]
-            pt = np.asarray(pages, np.int32)
-            # ring tables (§12): position p lives on ring page
-            # (p // ps) % n_ring — the host-side half of the §7 rolling
-            # reconstruction; the kernel recovers kpos from the slot
-            pidx = pos // ps if ring is None else (pos // ps) % ring
-            token_pages[cu[i]:cu[i + 1]] = pt[pidx]
-            token_offs[cu[i]:cu[i + 1]] = pos % ps
-            if ar.state_slots:
-                state_map[i] = ar.state_pages[seg.session]
-
-        t0 = time.perf_counter()
-        last, ids, new_arena = px.mixed_step_paged(
-            self.params, jnp.asarray(stream.tokens),
-            jnp.asarray(stream.positions), jnp.asarray(token_pages),
-            jnp.asarray(token_offs), jnp.asarray(page_table),
-            jnp.asarray(stream.cu_seqlens), jnp.asarray(stream.q_offsets),
-            jnp.asarray(stream.kv_lengths), ar.arena,
-            jnp.asarray(stream.last_idx), jnp.asarray(state_map),
-            n_decode=stream.decode_tokens)
-        toks, last_np = self._tokens_from_step(sessions, last, ids)
-        elapsed = time.perf_counter() - t0
-        px.note_padding(stream.total_tokens, bucket)
-        ar.replace(new_arena)
-        out: Dict[int, int] = {}
-        for i, seg in enumerate(segments):
-            ar.commit(seg.session, seg.tokens)
-            if self.draft is not None:
-                if seg.kind == "decode":
-                    self.draft.observe(seg.session, [int(seg.tokens[0])])
-                else:
-                    self.draft.observe(seg.session,
-                                       [int(t) for t in seg.tokens],
-                                       prompt=True)
-            out[seg.session] = int(toks[i])
-            if last_np is not None:
-                self.last_logits[seg.session] = last_np[i]
+            t0 = time.perf_counter()
+            with trace.span("laps.engine.packed.dispatch"):
+                last, ids, new_arena = px.mixed_step_paged(
+                    self.params, jnp.asarray(stream.tokens),
+                    jnp.asarray(stream.positions), jnp.asarray(token_pages),
+                    jnp.asarray(token_offs), jnp.asarray(page_table),
+                    jnp.asarray(stream.cu_seqlens),
+                    jnp.asarray(stream.q_offsets),
+                    jnp.asarray(stream.kv_lengths), ar.arena,
+                    jnp.asarray(stream.last_idx), jnp.asarray(state_map),
+                    n_decode=stream.decode_tokens)
+            toks, last_np = self._tokens_from_step(sessions, last, ids,
+                                                   "laps.engine.packed")
+            elapsed = time.perf_counter() - t0
+            out: Dict[int, int] = {}
+            with trace.span("laps.engine.packed.commit"):
+                px.note_padding(stream.total_tokens, bucket)
+                ar.replace(new_arena)
+                for i, seg in enumerate(segments):
+                    ar.commit(seg.session, seg.tokens)
+                    if self.draft is not None:
+                        if seg.kind == "decode":
+                            self.draft.observe(seg.session,
+                                               [int(seg.tokens[0])])
+                        else:
+                            self.draft.observe(seg.session,
+                                               [int(t) for t in seg.tokens],
+                                               prompt=True)
+                    out[seg.session] = int(toks[i])
+                    if last_np is not None:
+                        self.last_logits[seg.session] = last_np[i]
         if self.ecfg.measure:
             pre = [seg for seg in segments if seg.kind != "decode"]
             if pre:
@@ -1045,7 +1083,6 @@ class Engine:
                 for seg in pre:
                     self.samples.append((per, float(seg.length),
                                          float(seg.history)))
-        n_d = stream.decode_tokens
         return MixedStepResult(tokens=out, fused=True, bucket=bucket,
                                n_prefill=n - n_d, n_decode=n_d)
 
@@ -1405,26 +1442,29 @@ class Engine:
         cur = np.asarray(tokens, np.int32)
         out: Dict[int, List[int]] = {s: [] for s in sessions}
         for _ in range(steps):
-            hists = [self.arena.length(s) for s in sessions]
-            rows = packing.pad_decode_rows(
-                slots, hists, cur, bucket, park_position=park,
-                pad_token=self.ecfg.pad_token, pad_slot=self.arena.scratch)
-            logits, ids, new_arena = dx.decode(
-                self.params, jnp.asarray(rows.tokens),
-                jnp.asarray(rows.slot_map), jnp.asarray(rows.write_pos),
-                jnp.asarray(rows.kv_lengths), self.arena.arena)
-            self.arena.replace(new_arena)
-            dx.note_padding(n, bucket)
-            if self.draft is not None:
+            with trace.span("laps.engine.decode", rows=n, bucket=bucket):
+                hists = [self.arena.length(s) for s in sessions]
+                rows = packing.pad_decode_rows(
+                    slots, hists, cur, bucket, park_position=park,
+                    pad_token=self.ecfg.pad_token,
+                    pad_slot=self.arena.scratch)
+                logits, ids, new_arena = dx.decode(
+                    self.params, jnp.asarray(rows.tokens),
+                    jnp.asarray(rows.slot_map), jnp.asarray(rows.write_pos),
+                    jnp.asarray(rows.kv_lengths), self.arena.arena)
+                self.arena.replace(new_arena)
+                dx.note_padding(n, bucket)
+                if self.draft is not None:
+                    for i, s in enumerate(sessions):
+                        self.draft.observe(s, [int(cur[i])])
+                toks, logits_np = self._tokens_from_step(
+                    sessions, logits, ids, "laps.engine.decode")
+                cur = toks.astype(np.int32)
                 for i, s in enumerate(sessions):
-                    self.draft.observe(s, [int(cur[i])])
-            toks, logits_np = self._tokens_from_step(sessions, logits, ids)
-            cur = toks.astype(np.int32)
-            for i, s in enumerate(sessions):
-                self.arena.set_length(s, hists[i] + 1)
-                out[s].append(int(cur[i]))
-                if logits_np is not None:
-                    self.last_logits[s] = logits_np[i]
+                    self.arena.set_length(s, hists[i] + 1)
+                    out[s].append(int(cur[i]))
+                    if logits_np is not None:
+                        self.last_logits[s] = logits_np[i]
         return out
 
     def _decode_batch_paged(self, sessions: Sequence[int],
@@ -1443,48 +1483,55 @@ class Engine:
         cur = np.asarray(tokens, np.int32)
         out: Dict[int, List[int]] = {s: [] for s in sessions}
         for _ in range(steps):
-            hists = [ar.length(s) for s in sessions]
-            assert all(h > 0 for h in hists), \
-                f"paged decode on an empty session: {list(sessions)}"
-            tok = np.full(bucket, self.ecfg.pad_token, np.int32)
-            tok[:n] = cur
-            ring = ar.ring_pages
-            positions = np.full(bucket, ar.max_len - 1, np.int32)
-            write_pages = np.full(bucket, ar.scratch, np.int32)
-            write_offs = np.full(bucket, ps - 1, np.int32)
-            page_table = np.full((bucket, ar.max_pages_per_seq),
-                                 ar.scratch, np.int32)
-            kv_lengths = np.ones(bucket, np.int32)
-            state_map = np.full(bucket, ar.scratch, np.int32)
-            for i, (s, h) in enumerate(zip(sessions, hists)):
-                pages = ar.prepare_extend(s, 1)
-                page_table[i, :len(pages)] = pages
-                positions[i] = h
-                pidx = h // ps if ring is None else (h // ps) % ring
-                write_pages[i] = pages[pidx]
-                write_offs[i] = h % ps
-                kv_lengths[i] = h + 1
-                if ar.state_slots:
-                    state_map[i] = ar.state_pages[s]
-            logits, ids, new_arena = dx.decode_paged(
-                self.params, jnp.asarray(tok), jnp.asarray(positions),
-                jnp.asarray(write_pages), jnp.asarray(write_offs),
-                jnp.asarray(page_table), jnp.asarray(kv_lengths), ar.arena,
-                jnp.asarray(state_map))
-            ar.replace(new_arena)
-            dx.note_padding(n, bucket)
-            # the KV written this tick belongs to the INPUT token — the
-            # radix index must see the ids whose keys occupy the pages
-            for i, s in enumerate(sessions):
-                ar.commit(s, [int(cur[i])])
-                if self.draft is not None:
-                    self.draft.observe(s, [int(cur[i])])
-            toks, logits_np = self._tokens_from_step(sessions, logits, ids)
-            cur = toks.astype(np.int32)
-            for i, s in enumerate(sessions):
-                out[s].append(int(cur[i]))
-                if logits_np is not None:
-                    self.last_logits[s] = logits_np[i]
+            with trace.span("laps.engine.decode", rows=n, bucket=bucket):
+                with trace.span("laps.engine.decode.assemble"):
+                    hists = [ar.length(s) for s in sessions]
+                    assert all(h > 0 for h in hists), \
+                        f"paged decode on an empty session: {sessions}"
+                    tok = np.full(bucket, self.ecfg.pad_token, np.int32)
+                    tok[:n] = cur
+                    ring = ar.ring_pages
+                    positions = np.full(bucket, ar.max_len - 1, np.int32)
+                    write_pages = np.full(bucket, ar.scratch, np.int32)
+                    write_offs = np.full(bucket, ps - 1, np.int32)
+                    page_table = np.full((bucket, ar.max_pages_per_seq),
+                                         ar.scratch, np.int32)
+                    kv_lengths = np.ones(bucket, np.int32)
+                    state_map = np.full(bucket, ar.scratch, np.int32)
+                    for i, (s, h) in enumerate(zip(sessions, hists)):
+                        pages = ar.prepare_extend(s, 1)
+                        page_table[i, :len(pages)] = pages
+                        positions[i] = h
+                        pidx = h // ps if ring is None else (h // ps) % ring
+                        write_pages[i] = pages[pidx]
+                        write_offs[i] = h % ps
+                        kv_lengths[i] = h + 1
+                        if ar.state_slots:
+                            state_map[i] = ar.state_pages[s]
+                with trace.span("laps.engine.decode.dispatch"):
+                    logits, ids, new_arena = dx.decode_paged(
+                        self.params, jnp.asarray(tok),
+                        jnp.asarray(positions), jnp.asarray(write_pages),
+                        jnp.asarray(write_offs), jnp.asarray(page_table),
+                        jnp.asarray(kv_lengths), ar.arena,
+                        jnp.asarray(state_map))
+                    ar.replace(new_arena)
+                    dx.note_padding(n, bucket)
+                # the KV written this tick belongs to the INPUT token —
+                # the radix index must see the ids whose keys occupy the
+                # pages
+                with trace.span("laps.engine.decode.commit"):
+                    for i, s in enumerate(sessions):
+                        ar.commit(s, [int(cur[i])])
+                        if self.draft is not None:
+                            self.draft.observe(s, [int(cur[i])])
+                toks, logits_np = self._tokens_from_step(
+                    sessions, logits, ids, "laps.engine.decode")
+                cur = toks.astype(np.int32)
+                for i, s in enumerate(sessions):
+                    out[s].append(int(cur[i]))
+                    if logits_np is not None:
+                        self.last_logits[s] = logits_np[i]
         return out
 
     def _decode_batch_dense(self, sessions: Sequence[int],

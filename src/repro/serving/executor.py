@@ -31,6 +31,7 @@ from repro.core.buckets import (DEFAULT_DECODE_BUCKETS, DEFAULT_TOKEN_BUCKETS,
                                 DecodeBucketLadder, TokenBucketLadder)
 from repro.models import transformer as tr
 from repro.models.config import ModelConfig
+from repro.serving import trace
 
 
 def make_prefill_fn(cfg: ModelConfig) -> Callable:
@@ -231,7 +232,11 @@ class _ExecutorBase:
             self.misses += 1
             self.kind_misses[kind] = self.kind_misses.get(kind, 0) + 1
             t0 = time.perf_counter()
-            exe = jitted.lower(*args).compile()
+            # args[1] is the token operand of every step: its shape is
+            # the rung
+            rung = "x".join(map(str, args[1].shape))
+            with trace.span("laps.exec.compile", kind=kind, rung=rung):
+                exe = jitted.lower(*args).compile()
             self.compile_times[key] = time.perf_counter() - t0
             self._compiled[key] = exe
         else:
@@ -404,9 +409,6 @@ class PackedBucketExecutor(_ExecutorBase):
         # the shape key is (token bucket, max_seqs), not the segment mix)
         self.mixed_steps = 0
         self.decode_tokens_fused = 0
-        # speculative counters: verify dispatches and draft rows verified
-        self.verify_steps = 0
-        self.verify_rows = 0
 
     # ------------------------------------------------------------ lookup
     @property
@@ -515,8 +517,6 @@ class PackedBucketExecutor(_ExecutorBase):
         segment at once, returning (logits (B, L, V), greedy_ids (B, L),
         new_arena).  Kernel-identical to :meth:`mixed_step_arena` — only
         the final logits gather widens from 1 to L rows per segment."""
-        self.verify_steps += 1
-        self.verify_rows += int(gather_idx.shape[0] * gather_idx.shape[1])
         args = (params, tokens, positions, seg_slots, slot_map, cu_seqlens,
                 q_offsets, kv_lengths, arena, gather_idx)
         exe = self._get("verify_arena", self._jit_verify_arena, args)
@@ -527,8 +527,6 @@ class PackedBucketExecutor(_ExecutorBase):
                           kv_lengths, arena, gather_idx, state_map):
         """Paged speculative verification dispatch (DESIGN.md §10) —
         :meth:`verify_step_arena` over the shared page pool."""
-        self.verify_steps += 1
-        self.verify_rows += int(gather_idx.shape[0] * gather_idx.shape[1])
         args = (params, tokens, positions, token_pages, token_offs,
                 page_table, cu_seqlens, q_offsets, kv_lengths, arena,
                 gather_idx, state_map)

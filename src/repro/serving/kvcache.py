@@ -33,6 +33,7 @@ import jax.numpy as jnp
 
 from repro.models import transformer as tr
 from repro.models.config import ModelConfig
+from repro.serving import trace
 
 
 class KVArena:
@@ -416,6 +417,7 @@ class PagedKVArena:
         self._refcount[page] = 1
         return page
 
+    @trace.traced("laps.kv.evict")
     def _evict(self, need: int) -> None:
         """LRU-evict leaf pages held ONLY by the radix index; with a
         host tier configured the victim is DEMOTED (one device_get)
@@ -621,6 +623,7 @@ class PagedKVArena:
             d += 1
         return d * ps
 
+    @trace.traced("laps.kv.adopt")
     def match_prefix(self, session: int, tokens: Sequence[int]) -> int:
         """Map the longest indexed prefix of ``tokens`` onto existing
         pages; the session then only prefills ``tokens[matched:]``.
@@ -658,6 +661,7 @@ class PagedKVArena:
                             self.state_pages[session])
         return matched
 
+    @trace.traced("laps.kv.adopt")
     def match_extend(self, session: int, tokens: Sequence[int]) -> int:
         """CHUNK-LEVEL prefix matching (DESIGN.md §12): mid-request, map
         the longest indexed continuation of the session's cached history

@@ -32,6 +32,7 @@ from repro.core.scheduler import BasePolicy, ChunkWork
 from repro.core.slo import SLOTracker
 from repro.serving import packing
 from repro.serving.engine import Engine
+from repro.serving import trace
 from repro.serving.sampling import SamplingParams
 
 
@@ -155,82 +156,84 @@ class ServeLoop:
         token and every generated token, on the fused mixed path and
         the bucketed decode path alike — every path ends in the same
         logits gather."""
-        now = self.clock()
-        ddl = deadline if deadline is not None else \
-            (now + self.slo if self.slo else None)
-        if self.max_queue is not None or self.admission is not None:
-            r = self._admission_gate(session, tokens, now, ddl)
-            if r is not None:
+        with trace.span("laps.loop.submit", engine=self.engine_id):
+            now = self.clock()
+            ddl = deadline if deadline is not None else \
+                (now + self.slo if self.slo else None)
+            if self.max_queue is not None or self.admission is not None:
+                r = self._admission_gate(session, tokens, now, ddl)
+                if r is not None:
+                    return r
+            # a new turn preempts any generation still running on the session
+            # — including decode budgets of EARLIER turns still queued: those
+            # tokens will never be generated, so the pending-token estimate
+            # must forget them too
+            preempted = self.active_decodes.pop(session, 0)
+            # the preempted turn's sampled-but-unwritten token never reaches
+            # the cache — the new turn prefills right after the committed
+            # history, so the recovery transcript must forget it too
+            self._cache_pending.pop(session, None)
+            for p in self._tokens.values():
+                if p.req.session == session and p.decode_tokens:
+                    preempted += p.decode_tokens
+                    p.decode_tokens = 0
+            self._dec_pending(session, preempted)
+            self.engine.open_session(session)
+            self.engine.set_sampling(session, sampling)
+            pending = self._session_pending.get(session, 0)
+            # history ESTIMATE: cache length now plus every queued-but-
+            # unwritten token of this session.  Reading engine.history
+            # alone is stale the moment a second turn is submitted before
+            # the first dispatches — wrong dual-queue classification and
+            # AWD billing.  The estimate is refined to the exact cache
+            # length at dispatch time.
+            hist = self.engine.history(session) + pending
+            # paged engines with a radix prefix index: adopt the longest
+            # indexed prefix of the prompt RIGHT HERE, so length-aware
+            # classification, the AWD token budget, and the long-prefill
+            # chunker all see (and slice) exactly the true suffix — the
+            # matched pages are refcount-pinned while the request waits and
+            # the prefill step only ever touches tokens past them (§8).
+            # Adoption is gated on a TRULY empty session: adopting under a
+            # queued prior turn would bump the arena length and corrupt the
+            # queued turn's write offset.
+            prompt = np.asarray(tokens)
+            # §12 wait-for-fill: a COLD submit whose full-page prefix is
+            # already being filled by an in-flight request parks behind that
+            # filler — it re-enters intake on fill completion and adopts the
+            # indexed pages instead of prefilling them a second time
+            key = self._fill_key(prompt) if hist == 0 else None
+            if key is not None and key in self._active_fills:
+                r = Request(new_tokens=len(prompt), history_tokens=0,
+                            arrival=now, deadline=ddl, session=session)
+                self._fill_waiters[key].append(
+                    (r, prompt, decode_tokens, sampling))
+                self._session_pending[session] = \
+                    pending + len(prompt) + decode_tokens
+                self._outstanding += 1
+                self.coalesced_prefills += 1
                 return r
-        # a new turn preempts any generation still running on the session
-        # — including decode budgets of EARLIER turns still queued: those
-        # tokens will never be generated, so the pending-token estimate
-        # must forget them too
-        preempted = self.active_decodes.pop(session, 0)
-        # the preempted turn's sampled-but-unwritten token never reaches
-        # the cache — the new turn prefills right after the committed
-        # history, so the recovery transcript must forget it too
-        self._cache_pending.pop(session, None)
-        for p in self._tokens.values():
-            if p.req.session == session and p.decode_tokens:
-                preempted += p.decode_tokens
-                p.decode_tokens = 0
-        self._dec_pending(session, preempted)
-        self.engine.open_session(session)
-        self.engine.set_sampling(session, sampling)
-        pending = self._session_pending.get(session, 0)
-        # history ESTIMATE: cache length now plus every queued-but-unwritten
-        # token of this session.  Reading engine.history alone is stale the
-        # moment a second turn is submitted before the first dispatches —
-        # wrong dual-queue classification and AWD billing.  The estimate is
-        # refined to the exact cache length at dispatch time.
-        hist = self.engine.history(session) + pending
-        # paged engines with a radix prefix index: adopt the longest
-        # indexed prefix of the prompt RIGHT HERE, so length-aware
-        # classification, the AWD token budget, and the long-prefill
-        # chunker all see (and slice) exactly the true suffix — the
-        # matched pages are refcount-pinned while the request waits and
-        # the prefill step only ever touches tokens past them (§8).
-        # Adoption is gated on a TRULY empty session: adopting under a
-        # queued prior turn would bump the arena length and corrupt the
-        # queued turn's write offset.
-        prompt = np.asarray(tokens)
-        # §12 wait-for-fill: a COLD submit whose full-page prefix is
-        # already being filled by an in-flight request parks behind that
-        # filler — it re-enters intake on fill completion and adopts the
-        # indexed pages instead of prefilling them a second time
-        key = self._fill_key(prompt) if hist == 0 else None
-        if key is not None and key in self._active_fills:
-            r = Request(new_tokens=len(prompt), history_tokens=0,
-                        arrival=now, deadline=ddl, session=session)
-            self._fill_waiters[key].append(
-                (r, prompt, decode_tokens, sampling))
+            reusable = self.engine.adopt_prefix(session, prompt) if hist == 0 \
+                else 0
+            tokens = prompt[reusable:]
+            r = Request(new_tokens=len(tokens),
+                        history_tokens=hist + reusable,
+                        arrival=now, deadline=ddl,
+                        session=session, reusable_prefix=reusable)
+            self._tokens[r.rid] = PendingRequest(r, tokens, decode_tokens,
+                                                 prompt=prompt,
+                                                 sampling=sampling)
             self._session_pending[session] = \
-                pending + len(prompt) + decode_tokens
+                pending + len(tokens) + decode_tokens
+            self.policy.enqueue(r, now)
             self._outstanding += 1
-            self.coalesced_prefills += 1
+            # this request will index new full pages: register it as the
+            # filler so identical cold submits park instead of duplicating
+            if key is not None and key not in self._active_fills and \
+                    reusable < len(key):
+                self._active_fills[key] = r.rid
+                self._fill_waiters[key] = []
             return r
-        reusable = self.engine.adopt_prefix(session, prompt) if hist == 0 \
-            else 0
-        tokens = prompt[reusable:]
-        r = Request(new_tokens=len(tokens),
-                    history_tokens=hist + reusable,
-                    arrival=now, deadline=ddl,
-                    session=session, reusable_prefix=reusable)
-        self._tokens[r.rid] = PendingRequest(r, tokens, decode_tokens,
-                                             prompt=prompt,
-                                             sampling=sampling)
-        self._session_pending[session] = \
-            pending + len(tokens) + decode_tokens
-        self.policy.enqueue(r, now)
-        self._outstanding += 1
-        # this request will index new full pages: register it as the
-        # filler so identical cold submits park instead of duplicating
-        if key is not None and key not in self._active_fills and \
-                reusable < len(key):
-            self._active_fills[key] = r.rid
-            self._fill_waiters[key] = []
-        return r
 
     def _fill_key(self, prompt: np.ndarray) -> Optional[Tuple[int, ...]]:
         """Coalescing key: the prompt's full-page chunk prefix (≥ 1 full
@@ -568,57 +571,68 @@ class ServeLoop:
         periodically re-fit the §2.1 boundary.  Returns ``(did_work,
         wake_time)`` so multi-engine drivers (ServeCluster) can
         interleave many loops without nesting their drain loops."""
-        now = self.clock()
-        self.ticks += 1
-        self.policy.note_decode_backlog(
-            len(self.active_decodes),
-            tokens_per_decode=self._tokens_per_decode())
-        work, wake = self.policy.next_work(now)
-        if work is not None and self.faults is not None and \
-                self.faults.dispatch_fails(self.engine_id, self.ticks):
-            # §11 injected dispatch exception: the engine never ran, so
-            # the work re-enters the queue untouched.  A Batch was popped
-            # by next_work — push its requests back (state intact: they
-            # are still in _tokens, never dispatched).  A ChunkWork
-            # retries for free: skipping on_complete leaves the chunk
-            # progress unadvanced, so the same chunk is offered again.
-            self.dispatch_faults += 1
+        with trace.span("laps.loop.tick", engine=self.engine_id):
+            now = self.clock()
+            self.ticks += 1
+            self.policy.note_decode_backlog(
+                len(self.active_decodes),
+                tokens_per_decode=self._tokens_per_decode())
+            with trace.span("laps.loop.next_work"):
+                work, wake = self.policy.next_work(now)
+            if work is not None and self.faults is not None and \
+                    self.faults.dispatch_fails(self.engine_id, self.ticks):
+                # §11 injected dispatch exception: the engine never ran,
+                # so the work re-enters the queue untouched.  A Batch was
+                # popped by next_work — push its requests back (state
+                # intact: they are still in _tokens, never dispatched).
+                # A ChunkWork retries for free: skipping on_complete
+                # leaves the chunk progress unadvanced, so the same chunk
+                # is offered again.
+                self.dispatch_faults += 1
+                if isinstance(work, Batch) and work.requests:
+                    for r in work.requests:
+                        self.policy.enqueue(r, now)
+                    self.tracker.note_retried(len(work.requests))
+                else:
+                    self.tracker.note_retried(1)
+                return True, wake
+            did = True
             if isinstance(work, Batch) and work.requests:
-                for r in work.requests:
-                    self.policy.enqueue(r, now)
-                self.tracker.note_retried(len(work.requests))
+                with trace.span("laps.loop.run_batch",
+                                requests=len(work.requests)):
+                    self._run_batch(work)
+                    self.policy.on_complete(work, self.clock())
+            elif isinstance(work, ChunkWork):
+                with trace.span("laps.loop.run_chunk", rid=work.req.rid,
+                                tokens=work.chunk_tokens):
+                    self._run_chunk(work)
+                    self.policy.on_complete(work, self.clock())
+            elif self.active_decodes:
+                # the decode backlog fills what would be an idle wait —
+                # temporal sharing without a separate decode phase
+                with trace.span("laps.loop.decode_only",
+                                rows=len(self.active_decodes)):
+                    self._run_decode_only()
             else:
-                self.tracker.note_retried(1)
-            return True, wake
-        did = True
-        if isinstance(work, Batch) and work.requests:
-            self._run_batch(work)
-            self.policy.on_complete(work, self.clock())
-        elif isinstance(work, ChunkWork):
-            self._run_chunk(work)
-            self.policy.on_complete(work, self.clock())
-        elif self.active_decodes:
-            # the decode backlog fills what would be an idle wait —
-            # temporal sharing without a separate decode phase
-            self._run_decode_only()
-        else:
-            did = False
-        if self.engine.spec_dispatches:
-            # mirror the engine's speculative totals into the tracker so
-            # cluster-merged SLO reports carry acceptance statistics
-            self.tracker.note_spec(self.engine.tokens_drafted,
-                                   self.engine.tokens_accepted,
-                                   self.engine.spec_dispatches,
-                                   self.engine.spec_committed)
-        self._since_fit += 1
-        if self._since_fit >= self.refit_every:
-            self._since_fit = 0
-            fit = self.engine.fit_boundary()
-            if fit is not None and hasattr(self.policy, "dq") and \
-                    self.policy.dq.override is None:
-                self.policy.dq.model = None  # fitted threshold wins
-                self.policy.dq.override = fit.boundary()
-        return did, wake
+                did = False
+            if self.engine.spec_dispatches:
+                # mirror the engine's speculative totals into the tracker
+                # so cluster-merged SLO reports carry acceptance statistics
+                self.tracker.note_spec(self.engine.tokens_drafted,
+                                       self.engine.tokens_accepted,
+                                       self.engine.spec_dispatches,
+                                       self.engine.spec_committed)
+            self._since_fit += 1
+            if self._since_fit >= self.refit_every:
+                self._since_fit = 0
+                with trace.span("laps.loop.refit",
+                                samples=len(self.engine.samples)):
+                    fit = self.engine.fit_boundary()
+                if fit is not None and hasattr(self.policy, "dq") and \
+                        self.policy.dq.override is None:
+                    self.policy.dq.model = None  # fitted threshold wins
+                    self.policy.dq.override = fit.boundary()
+            return did, wake
 
     def run_until_idle(self, max_wall: float = 60.0) -> None:
         """Drive the unified tick until every prefill AND every session's
