@@ -31,6 +31,7 @@ import numpy as np
 
 from repro.core import boundary as boundary_mod
 from repro.kernels import ops as kernel_ops
+from repro.kernels.decode_attn import paged_decode_blocks
 from repro.core.buckets import (DEFAULT_DECODE_BUCKETS, DEFAULT_TOKEN_BUCKETS,
                                 BucketGrid)
 from repro.models import transformer as tr
@@ -1483,7 +1484,16 @@ class Engine:
         cur = np.asarray(tokens, np.int32)
         out: Dict[int, List[int]] = {s: [] for s in sessions}
         for _ in range(steps):
-            with trace.span("laps.engine.decode", rows=n, bucket=bucket):
+            args = dict(rows=n, bucket=bucket)
+            if trace.enabled():
+                # the attention kernel's walk over the real rows, beside
+                # bucket × max_pages_per_seq pages of the table
+                args["kv_blocks"] = paged_decode_blocks(
+                    [ar.length(s) + 1 for s in sessions], page_size=ps,
+                    p_max=ar.max_pages_per_seq,
+                    window=None if ar.ring_pages is None
+                    else tr.arena_capability(self.cfg).window)
+            with trace.span("laps.engine.decode", **args):
                 with trace.span("laps.engine.decode.assemble"):
                     hists = [ar.length(s) for s in sessions]
                     assert all(h > 0 for h in hists), \

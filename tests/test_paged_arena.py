@@ -9,6 +9,8 @@ branches match independently prefilled sessions through decode across
 page boundaries), and page hygiene — pad rows only ever touch the
 reserved scratch page.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -17,7 +19,8 @@ import pytest
 from repro.configs import get_smoke
 from repro.kernels import ops as kernel_ops
 from repro.kernels import ref
-from repro.kernels.decode_attn import decode_attn_paged
+from repro.kernels.decode_attn import (decode_attn_paged,
+                                       pages_per_block, paged_decode_blocks)
 from repro.kernels.ragged_prefill import ragged_prefill_paged
 from repro.models import transformer as tr
 from repro.serving import Engine, EngineConfig
@@ -109,27 +112,101 @@ def test_paged_prefill_kernel_full_table():
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5)
 
 
-@pytest.mark.parametrize("hq,hkv", [(8, 2), (4, 4), (8, 1)])
-def test_paged_decode_kernel_matches_oracle(hq, hkv):
-    """COW-forked decode layout: two rows share every prefix page and
-    diverge only on their (copied) boundary page."""
+# (lengths, table width, window) of each decode case; page size 16, so a
+# kernel block is 16 pages (256 tokens) wherever the table is that wide
+BLK = 16 * pages_per_block(16, 64)
+DECODE_CASES = {
+    "len-1": ([1], 24, None),
+    "len-ps": ([16], 24, None),
+    "len-ps+1": ([17], 24, None),
+    "one-block": ([BLK], 24, None),
+    "one-block+1": ([BLK + 1], 24, None),
+    "full-table": ([512 * 16, 700], 512, None),
+    "pad-rows": ([40, 1, 300, 1], 24, None),
+    "shared-pages": ([300, 300, 290, 20], 24, None),
+    "mixed-bucket": ([1, 15, 16, 17, BLK - 1, BLK, BLK + 1, 380], 24,
+                     None),
+    # an 18-page ring (288 slots) under a 256-token window: the walk
+    # starts mid-ring and, once the ring has wrapped, crosses its end
+    "ring-before-wrap": ([1, 100, 256, 257, 288], 18, 256),
+    "ring-after-wrap": ([289, 300, 545, 600, 1000], 18, 256),
+}
+
+
+def _decode_layout(case, rng):
+    """Pool size, page table and lengths of one decode case: distinct
+    random pages per row; pad rows (length 1) sit on the scratch page,
+    the pool's last, as the engine parks them; in ``shared-pages`` rows
+    1 and 2 share row 0's first 16 pages (a whole block) and row 3 its
+    first page."""
+    lengths, p_max, window = DECODE_CASES[case]
+    ps = 16
+    held = [min(n, p_max * ps) for n in lengths]
+    npages = sum(-(-n // ps) for n in held) + 1
+    scratch = npages - 1
+    table = page_layout(rng, scratch, ps, p_max, held, [0] * len(held))
+    for i, n in enumerate(lengths):
+        if n == 1 and case == "pad-rows":
+            table[i] = scratch
+    if case == "shared-pages":
+        table[1, :16] = table[0, :16]
+        table[2, :16] = table[0, :16]
+        table[3, :1] = table[0, :1]
+    return npages, ps, table, np.asarray(lengths, np.int32), window
+
+
+@pytest.mark.parametrize("case,hq,hkv", [
+    pytest.param("cow-fork", 8, 2, id="8-2"),
+    pytest.param("cow-fork", 4, 4, id="4-4"),
+    pytest.param("cow-fork", 8, 1, id="8-1"),
+] + [pytest.param(c, 8, 2, id=c) for c in DECODE_CASES])
+def test_paged_decode_kernel_matches_oracle(case, hq, hkv):
+    """The page-table walk reads exactly the pages the gathering oracle
+    sees: lengths at and around page and block edges, a full table, pad
+    rows, shared pages, a mixed bucket, the windowed ring before and
+    after it wraps, and a COW-forked layout (``cow-fork``: two rows
+    share every prefix page and diverge only on their copied boundary
+    page) under GQA, MHA and MQA."""
     ks = jax.random.split(KEY, 3)
-    npages, ps, p_max, d, b = 20, 16, 4, 32, 4
     rng = np.random.default_rng(5)
-    lengths = np.asarray([37, 37, 9, 51], np.int32)
-    table = page_layout(rng, npages, ps, p_max,
-                        list(lengths), [0] * b)
-    # rows 0/1: a fork — shared full pages, distinct boundary pages
-    table[1, :2] = table[0, :2]
-    assert table[1, 2] != table[0, 2]
+    d = 32
+    if case == "cow-fork":
+        npages, ps, p_max, window = 20, 16, 4, None
+        lengths = np.asarray([37, 37, 9, 51], np.int32)
+        table = page_layout(rng, npages, ps, p_max, list(lengths),
+                            [0] * len(lengths))
+        table[1, :2] = table[0, :2]
+        assert table[1, 2] != table[0, 2]
+    else:
+        npages, ps, table, lengths, window = _decode_layout(case, rng)
+    b = len(lengths)
     q = rand(ks[0], (b, hq, d))
     k = rand(ks[1], (npages, ps, hkv, d))
     v = rand(ks[2], (npages, ps, hkv, d))
     out = decode_attn_paged(q, k, v, jnp.asarray(table),
-                            jnp.asarray(lengths))
+                            jnp.asarray(lengths), window=window)
     want = ref.ref_decode_attn_paged(q, k, v, jnp.asarray(table),
-                                     jnp.asarray(lengths))
+                                     jnp.asarray(lengths), window=window)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5)
+
+
+def test_paged_decode_blocks_counts_the_walk():
+    """The host's count of blocks walked, which the decode span
+    reports: one per started block of a row's pages, at most
+    ceil(window / ps) + 1 pages on a ring."""
+    ppb = pages_per_block(16, 512)
+    assert ppb == 16
+    count = functools.partial(paged_decode_blocks, page_size=16, p_max=512)
+    assert count([1]) == 1
+    assert count([BLK, BLK + 1]) == 1 + 2
+    assert count([8192, 1, 1]) == 32 + 2
+    ring = functools.partial(paged_decode_blocks, page_size=16, p_max=18,
+                             window=256)
+    assert ring([256]) == 1                # 16 pages, from entry 0
+    assert ring([257]) == 2                # 17 pages: the walk starts at 0
+    assert ring([300]) == 2                # starts mid-page, 17 pages
+    assert ring([10_000]) == 1             # starts on a page edge
+    assert ring([10_001]) == 2
 
 
 def test_ops_dispatch_paged_backends_agree():

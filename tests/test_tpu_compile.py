@@ -69,14 +69,20 @@ def test_ragged_prefill_paged_compiles_for_v5e(one_chip, tokens, window):
 
 
 @pytest.mark.parametrize("window", [None, WINDOW])
-@pytest.mark.parametrize("rows", [1, 8])
-def test_decode_attn_paged_compiles_for_v5e(one_chip, rows, window):
+@pytest.mark.parametrize("rows,p_max,n_pages", [
+    pytest.param(1, P_MAX, N_PAGES, id="1"),
+    pytest.param(8, P_MAX, N_PAGES, id="8"),
+    # the benchmark's deployment: 16 sessions, max_len 8,192, 2,689 pages
+    pytest.param(16, 512, 2689, id="16-bench"),
+])
+def test_decode_attn_paged_compiles_for_v5e(one_chip, rows, p_max, n_pages,
+                                            window):
     s = lambda shape, dt=jnp.int32: _shape(one_chip, shape, dt)
-    pool = s((N_PAGES, PS, HKV, D), jnp.bfloat16)
+    pool = s((n_pages, PS, HKV, D), jnp.bfloat16)
     txt = _compiled_text(
         lambda q, k, v, pt, kl: decode_attn.decode_attn_paged(
             q, k, v, pt, kl, window=window, interpret=False),
-        s((rows, HQ, D), jnp.bfloat16), pool, pool, s((rows, P_MAX)),
+        s((rows, HQ, D), jnp.bfloat16), pool, pool, s((rows, p_max)),
         s((rows,)))
     assert "tpu_custom_call" in txt
 

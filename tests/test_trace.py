@@ -151,6 +151,18 @@ def test_spans_cover_every_layer(served):
     assert all(s.args["rung"] for s in compiles)
 
 
+def test_paged_decode_span_counts_the_kernel_walk(served):
+    """``kv_blocks``: the blocks the paged decode kernel walks, one per
+    real row here (96 tokens at most fit one block of a 12-page table);
+    the slot arena's decode step has no page walk to count."""
+    paged = [s for s in served[1]["trace"]["spans"]
+             if s.name == "laps.engine.decode"]
+    assert paged and all(s.args["kv_blocks"] == s.args["rows"]
+                         for s in paged)
+    assert all("kv_blocks" not in s.args for s in served[2]["trace"]["spans"]
+               if s.name == "laps.engine.decode")
+
+
 def test_d2h_counter_counts_every_logits_row(served):
     on = served[1]
     eng = on["engine"]
