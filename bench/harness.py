@@ -358,41 +358,35 @@ def _req_view(r: Rec, end: float) -> Dict:
             "failed": r.done is None, "end": end}
 
 
-def _mirror_check(mcfg, hf: Dict, arch: Dict) -> None:
-    """The program's model config must be the one the reference reads."""
-    want = {"num_layers": hf["num_hidden_layers"],
-            "d_model": hf["hidden_size"],
-            "num_heads": hf["num_attention_heads"],
-            "num_kv_heads": hf["num_key_value_heads"],
-            "d_ff": hf["intermediate_size"], "vocab_size": hf["vocab_size"],
-            "hdim": hf.get("head_dim")
-            or hf["hidden_size"] // hf["num_attention_heads"],
-            "norm_eps": hf["rms_norm_eps"],
-            "rope_theta": hf["rope_theta"], "qk_norm": arch["qk_norm"],
-            "qkv_bias": arch["qkv_bias"], "dtype": arch["dtype"]}
-    got = {k: getattr(mcfg, k) for k in want}
-    if got != want:
+def mirror_check(mcfg, fields: Dict) -> None:
+    """The program's model config must be the one the reference reads:
+    ``fields`` are the attributes the configuration's published keys
+    fix (its architecture's ``program_fields``)."""
+    got = {k: getattr(mcfg, k) for k in fields}
+    if got != fields:
         raise ValueError(f"program config {got} differs from the "
-                         f"configuration file {want}")
+                         f"configuration file {fields}")
 
 
 def build(cell: manifest.Cell, seed: int):
-    """Weights, engine and loop as the program's entry builds them."""
+    """Weights, engine and loop as the program's entry builds them; the
+    weights and the config mirror come from the module of the
+    architecture the configuration names."""
     import jax
     from repro.configs import get_config
     from repro.launch import drive
     from repro.models import transformer as tr
-    from bench import weights as bw
 
     cf = cell.config
     hf, arch = cf["hf_config"], cf["architecture"]
+    mod = manifest.arch_module(cell)
     mcfg = get_config(cf["registry"]).replace(**cf.get("overrides", {}))
-    _mirror_check(mcfg, hf, arch)
+    mirror_check(mcfg, mod.program_fields(hf, arch))
     dep = cf["deployment"]
     sizes = drive.DriveSizes(sessions=int(dep["sessions"]),
                              max_len=int(dep["max_len"]),
                              num_pages=int(dep["num_pages"]))
-    params = bw.make(hf, arch, seed, jax.numpy.dtype(arch["dtype"]))
+    params = mod.make(hf, arch, seed, jax.numpy.dtype(arch["dtype"]))
     want = jax.tree.structure(tr.param_shapes(mcfg))
     if jax.tree.structure(params) != want or any(
             a.shape != b.shape for a, b in zip(
@@ -503,6 +497,7 @@ def serve(cell: manifest.Cell, seed: int, seconds: float, trace: bool,
         "free_pages_at_start": free0,
         "steps": traced_steps, "trace": red, "device": dev,
         "hf": cell.config["hf_config"],
+        "arch": cell.config["reference"], "bench_dir": str(cell.bench_dir),
         "peak": flops.peaks(dev["kind"]) if dev["platform"] != "cpu"
         else None,
         "compiles_in_window": compiles,

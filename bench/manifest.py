@@ -10,6 +10,12 @@ as new files plus new manifest entries, and edits no file already here:
   traffic/kinds/<kind>.py      one generator per kind
   metrics/<metric>.py          one reader per metric
   reference/<name>.py          the plain reference a config names
+  arch/<name>.py               the architecture of the same name: its
+                               weights, the program config it implies,
+                               its operation counts
+
+A configuration names its architecture once, under ``reference``: the
+plain reference and the architecture's module share that name.
 """
 from __future__ import annotations
 
@@ -82,3 +88,15 @@ def traffic_kind(cell: Cell) -> ModuleType:
 def reference_module(cell: Cell) -> ModuleType:
     return load_module(
         cell.bench_dir / "reference" / f"{cell.config['reference']}.py")
+
+
+def arch_by_name(bench_dir, name: str) -> ModuleType:
+    """``arch/<name>.py`` of the benchmark at ``bench_dir``: ``make`` and
+    ``shapes`` (the program's parameter tree drawn from a seed),
+    ``program_fields`` (the program config's attributes the published
+    keys fix), ``step_flops`` and ``kernel_counts``."""
+    return load_module(Path(bench_dir) / "arch" / f"{name}.py")
+
+
+def arch_module(cell: Cell) -> ModuleType:
+    return arch_by_name(cell.bench_dir, cell.config["reference"])

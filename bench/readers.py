@@ -4,13 +4,18 @@ Each reader gets the run record the harness builds (``harness.serve``)
 and returns a number, or None where it finds nothing to read (the
 metric is then left out of the result line).  A share of a roofline or
 of a peak is never returned as 0 for want of data.
+
+Operations and bytes come from the module of the architecture the run
+served (``rec["arch"]``, ``bench/arch/<name>.py``), never from counts
+written here for one architecture.
 """
 from __future__ import annotations
 
 import math
-from typing import Dict, List, Optional, Sequence
+from types import ModuleType
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
-from bench import flops
+from bench import flops, manifest
 
 
 def percentile(vals: Sequence[float], q: float) -> float:
@@ -42,13 +47,19 @@ def tpot_s(rec: Dict) -> List[float]:
     return out
 
 
+def arch(rec: Dict) -> ModuleType:
+    """The module of the architecture the run served."""
+    return manifest.arch_by_name(rec["bench_dir"], rec["arch"])
+
+
 def step_mfu(rec: Dict) -> Optional[float]:
     """Model FLOPs of the work the engine was handed in the traced
     window over (window x the chip's peak), in %."""
     red, peak = rec.get("trace"), rec.get("peak")
     if red is None or peak is None or not rec["steps"]:
         return None
-    fl = sum(flops.step_flops(rec["hf"], s["segs"]) for s in rec["steps"])
+    mod = arch(rec)
+    fl = sum(mod.step_flops(rec["hf"], s["segs"]) for s in rec["steps"])
     return 100.0 * fl / (red.window_s * red.devices * peak["bf16_flops"])
 
 
@@ -68,23 +79,36 @@ KERNELS = {
 }
 
 
-def kernel_seconds(rec: Dict, kernel: str) -> float:
-    op = KERNELS[kernel]["op"]
-    return sum(o.dur for o in rec["trace"].ops if o.name == op)
+Counts = Callable[[ModuleType, Dict, List[Tuple[int, int]]],
+                  List[Tuple[float, float, int]]]
 
 
-def roofline(rec: Dict, kernel: str) -> Optional[float]:
-    """Least time of the kernel's launches in the traced window (from
-    the segment lengths the engine was handed and the chip's peaks)
-    over the kernel's device time, in %."""
+def kernel_roofline(rec: Dict, op: str, step: str,
+                    counts: Counts) -> Optional[float]:
+    """Least time of the launches of device operation ``op`` in the
+    traced window over its device time, in %.  Each step of kind
+    ``step`` the engine was handed launched it once per layer;
+    ``counts(arch, hf, segments)`` gives one launch's ``(flops, bytes,
+    layers)`` for each group of layers that does the same work, and
+    each launch takes its least time at the chip's peaks."""
     red, peak = rec.get("trace"), rec.get("peak")
     if red is None or peak is None:
         return None
-    step = KERNELS[kernel]["step"]
     launches = [s["segs"] for s in rec["steps"] if s["kind"] == step]
-    dev = kernel_seconds(rec, kernel)
+    dev = sum(o.dur for o in red.ops if o.name == op)
     if not launches or dev <= 0:
         return None
-    least = sum(flops.kernel_least_time(rec["hf"], segs, peak)[0]
+    mod = arch(rec)
+    least = sum(sum(flops.least_time(fl, by, peak)[0] * n
+                    for fl, by, n in counts(mod, rec["hf"], segs))
                 for segs in launches)
     return 100.0 * least / dev
+
+
+def roofline(rec: Dict, kernel: str) -> Optional[float]:
+    """Roofline share of one of ``KERNELS``, counted by the
+    architecture's ``kernel_counts``."""
+    k = KERNELS[kernel]
+    return kernel_roofline(
+        rec, k["op"], k["step"],
+        lambda mod, hf, segs: mod.kernel_counts(hf, kernel, segs))

@@ -56,3 +56,22 @@ def test_throwaway_config_mix_kind_and_metric(tmp_path):
     e2e = harness.metrics(cell, rec, trace=False)
     assert set(e2e) == {"output_tok_s", "setup_s"}
     assert harness.verdict(cell, rec)["ok"]
+
+
+def test_throwaway_moe_architecture_as_new_files(tmp_path):
+    """A configuration of an architecture the benchmark does not have (a
+    router over 8 SwiGLU experts, top-2) is served and checked once its
+    module, its reference and its config are added as files."""
+    man_path = tiny.make_tree(tmp_path)
+    name = tiny.add_moe(man_path)
+    cell = manifest.load_cell(name, man_path)
+    arch = manifest.arch_module(cell)
+    assert arch.__file__.endswith("arch/moe_gqa.py")
+    rec = harness.serve(cell, 2**33 + 5, 0.8, False, time.monotonic())
+    assert rec["arch"] == "moe_gqa" and rec["requests"]
+    v = harness.verdict(cell, rec)
+    assert v["ok"] and v["tokens"] > 0, v
+    ffn = rec["params"]["blocks"][0]["ffn"]
+    assert ffn["router"].shape == (2, 64, 8)
+    assert ffn["wg"].shape == ffn["wi"].shape == (2, 8, 64, 32)
+    assert ffn["wo"].shape == (2, 8, 32, 64)

@@ -52,6 +52,7 @@ def test_cells_find_their_files():
         cell = manifest.load_cell(w["name"])
         assert callable(manifest.traffic_kind(cell).make_source)
         assert callable(manifest.reference_module(cell).logits)
+        assert callable(manifest.arch_module(cell).make)
         cf = cell.config
         assert cf["name"] == w["config"]
         assert cf["reference"] and cf["check"]["max_logit_gap"] > 0
@@ -67,24 +68,29 @@ def test_configs_state_their_cuts():
 
 
 def test_program_config_matches_the_file():
+    """Each configuration's architecture module says which program
+    config attributes its published keys fix; the program agrees."""
     from repro.configs import get_config
     from bench import harness
     for c in MAN["configs"]:
         cf = json.loads((manifest.ROOT / c["file"]).read_text())
+        arch = manifest.arch_by_name(manifest.BENCH_DIR, cf["reference"])
         mcfg = get_config(cf["registry"]).replace(**cf["overrides"])
-        harness._mirror_check(mcfg, cf["hf_config"], cf["architecture"])
+        harness.mirror_check(
+            mcfg, arch.program_fields(cf["hf_config"], cf["architecture"]))
 
 
 def test_weights_match_the_program_layout():
     import jax
     from repro.configs import get_config
     from repro.models import transformer as tr
-    from bench import weights
     for c in MAN["configs"]:
         cf = json.loads((manifest.ROOT / c["file"]).read_text())
+        arch = manifest.arch_by_name(manifest.BENCH_DIR, cf["reference"])
         mcfg = get_config(cf["registry"]).replace(**cf["overrides"])
-        got = weights.shapes(cf["hf_config"], cf["architecture"])
+        got = arch.shapes(cf["hf_config"], cf["architecture"],
+                          jax.numpy.dtype(cf["architecture"]["dtype"]))
         want = tr.param_shapes(mcfg)
         assert jax.tree.structure(got) == jax.tree.structure(want)
-        assert [a.shape for a in jax.tree.leaves(got)] == \
-            [a.shape for a in jax.tree.leaves(want)]
+        assert [(a.shape, a.dtype) for a in jax.tree.leaves(got)] == \
+            [(a.shape, a.dtype) for a in jax.tree.leaves(want)]

@@ -49,6 +49,7 @@ def test_roofline_reader_from_segments(red):
         "hidden_size": 2560, "intermediate_size": 9728,
         "num_hidden_layers": 36, "num_attention_heads": 32,
         "num_key_value_heads": 8, "head_dim": 128, "vocab_size": 151936},
+        "arch": "dense_gqa", "bench_dir": str(manifest.BENCH_DIR),
         "peak": {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}}
     share = readers.roofline(rec, "decode_attn")
     assert 0 < share < 100

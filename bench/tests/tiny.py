@@ -3,6 +3,9 @@
 ``make_tree(tmp)`` copies ``bench/`` into ``tmp`` and adds a two-layer
 qwen3-shaped configuration (d_model 64, vocab 256) and a closed-loop
 chat cell, sized so a whole run takes seconds on the CPU.
+``add_moe(manifest)`` adds to such a copy, as new files and manifest
+entries only, a mixture-of-experts configuration of an architecture the
+benchmark does not have (``moe_arch.py``, ``moe_reference.py``).
 """
 from __future__ import annotations
 
@@ -48,6 +51,23 @@ TRAFFIC = {
 }
 
 
+TINY_MOE_HF = {"hidden_size": 64, "intermediate_size": 96,
+               "moe_intermediate_size": 32, "num_experts": 8,
+               "num_experts_per_tok": 2, "norm_topk_prob": True,
+               "num_hidden_layers": 2, "num_attention_heads": 4,
+               "num_key_value_heads": 2, "head_dim": 16, "vocab_size": 256,
+               "rms_norm_eps": 1e-06, "rope_theta": 1000000.0}
+
+TINY_MOE = dict(
+    TINY, name="tiny-moe", source="a two-layer qwen3-moe-shaped test model",
+    hf_config=TINY_MOE_HF, registry="qwen3-moe-30b-a3b",
+    overrides={"num_layers": 2, "d_model": 64, "num_heads": 4,
+               "num_kv_heads": 2, "d_ff": 32, "moe_d_ff": 32,
+               "num_experts": 8, "num_experts_per_tok": 2,
+               "vocab_size": 256, "head_dim": 16, "dtype": "float32"},
+    reference="moe_gqa")
+
+
 def make_tree(tmp: Path) -> Path:
     """Returns the path of the copy's manifest."""
     dst = Path(tmp) / "bench"
@@ -71,3 +91,22 @@ def make_tree(tmp: Path) -> Path:
     path = Path(tmp) / "BENCHMARK.json"
     path.write_text(json.dumps(man))
     return path
+
+
+def add_moe(man_path: Path, config: dict = TINY_MOE) -> str:
+    """Add ``config`` (architecture ``moe_gqa``) and a chat cell on it
+    to the copy whose manifest is ``man_path``; returns the cell's
+    name."""
+    b = Path(man_path).parent / "bench"
+    here = Path(__file__).resolve().parent
+    shutil.copy(here / "moe_arch.py", b / "arch" / "moe_gqa.py")
+    shutil.copy(here / "moe_reference.py", b / "reference" / "moe_gqa.py")
+    name = config["name"]
+    (b / "configs" / f"{name}.json").write_text(json.dumps(config))
+    man = json.loads(Path(man_path).read_text())
+    man["configs"].append({"name": name,
+                           "file": f"bench/configs/{name}.json"})
+    man["workloads"].append({"name": f"{name}.chat", "config": name,
+                             "traffic": "tiny-chat", "chips": 1})
+    Path(man_path).write_text(json.dumps(man))
+    return f"{name}.chat"
